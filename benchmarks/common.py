@@ -1,8 +1,8 @@
 """Shared benchmark utilities.
 
-CPU wall-clock here is a *sanity signal only* (this container has no TPU);
-the graded numbers are the modeled roofline terms derived from the analytic
-planner and the compiled dry-run artifacts (EXPERIMENTS.md §Methodology).
+CPU wall-clock here is a *sanity signal only*, never a device time; the
+other numbers are modeled roofline terms derived from the analytic planner
+and the compiled dry-run artifacts.
 
 Besides the CSV ``emit`` lines, every bench function reports its numbers
 through :func:`record`, which forwards to the active

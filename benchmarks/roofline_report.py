@@ -1,5 +1,5 @@
-"""Aggregate experiments/dryrun JSONs into the EXPERIMENTS.md §Roofline
-table (markdown) and a CSV."""
+"""Aggregate experiments/dryrun JSONs into a roofline table (markdown) and
+a CSV."""
 import json
 import os
 

@@ -156,5 +156,5 @@ def get(name: str, smoke: bool = False) -> ArchConfig:
 def supports_shape(cfg: ArchConfig, shape: ShapeConfig) -> Tuple[bool, str]:
     """(runnable, reason-if-not) for an (arch, shape) cell."""
     if shape.name == "long_500k" and not cfg.sub_quadratic:
-        return False, "pure full-attention arch: O(L^2) at 512k (DESIGN.md §4)"
+        return False, "pure full-attention arch: O(L^2) at 512k"
     return True, ""

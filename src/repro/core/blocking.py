@@ -220,10 +220,30 @@ def modeled_traffic_bytes(
     )
 
 
+def decode_bytes_per_element(a_dtype, b_dtype) -> int:
+    """VMEM bytes per B element that the kernel's in-register payload
+    decode materializes (``kernels/mpgemm.py::_accumulate``).
+
+    int4 nibbles unpack through int32 into int8 rows (``unpack_nibbles``);
+    a quantized tile against a float A is scaled in f32 and cast to A's
+    dtype before the dot.  Dense and int x int8 paths decode nothing.
+    """
+    codec = get_codec(b_dtype)
+    if codec is None:
+        return 0
+    n = 4 + 1 if codec.elems_per_byte > 1 else 0
+    a = jnp.dtype(a_dtype)
+    if a.kind != "i":
+        n += 4 + a.itemsize
+    elif not codec.integer:
+        n += 4              # int A x float payload: both sides upcast
+    return n
+
+
 def vmem_working_set(
     bm: int, bn: int, bk: int,
     a_bytes: int, b_bytes: int, out_bytes: int, acc_bytes: int = 4,
-    beta: float = 0.0, extra_mn_inputs: int = 0,
+    beta: float = 0.0, extra_mn_inputs: int = 0, decode_bytes: int = 0,
 ) -> int:
     """Paper eq (1), VMEM form.
 
@@ -232,10 +252,13 @@ def vmem_working_set(
     Pallas pipeline's double buffering of the streamed inputs, plus the
     resident accumulator and the output staging block.  Each extra
     (M, N)-shaped epilogue operand (gated/residual fusions) streams one more
-    double-buffered (bm, bn) block.
+    double-buffered (bm, bn) block.  ``decode_bytes`` prices the in-VMEM
+    temporaries of a quantized payload's decode per (bk, bn) element
+    (:func:`decode_bytes_per_element`).
     """
     dbuf = 2  # double-buffered HBM->VMEM pipeline
     ws = dbuf * (bm * bk * a_bytes + bk * bn * b_bytes)
+    ws += bk * bn * decode_bytes       # payload decode temporaries
     ws += bm * bn * acc_bytes          # resident accumulator (the "ZA tiles")
     ws += bm * bn * out_bytes          # output staging
     if beta:
@@ -281,6 +304,7 @@ def plan_gemm(
     accb = _dtype_bytes(acc_dtype)
 
     budget = int(hw.vmem_bytes * vmem_budget_frac)
+    dec = decode_bytes_per_element(a_dtype, b_dtype)
     lane = hw.lane
     sub_a = _sublane(hw, a_dtype)   # A/acc second-minor granularity
     sub_b = _sublane(hw, b_dtype)   # B second-minor granularity (bk)
@@ -297,7 +321,7 @@ def plan_gemm(
         for bn in bn_cands:
             for bk in bk_cands:
                 ws = vmem_working_set(bm, bn, bk, ab, bb, ob, accb, beta,
-                                      extra_mn_inputs)
+                                      extra_mn_inputs, dec)
                 if ws > budget:
                     continue
                 traffic = modeled_traffic_bytes(m, n, k, bm, bn, ab, bb, ob,
@@ -362,7 +386,8 @@ def plan_with_blocks(
     bn = min(bn, _round_up(n, hw.lane))
     bk = min(bk, _round_up(k, bk_align))
     ws = vmem_working_set(bm, bn, bk, ab, bb, ob, accb, beta,
-                          extra_mn_inputs)
+                          extra_mn_inputs,
+                          decode_bytes_per_element(a_dtype, b_dtype))
     traffic = modeled_traffic_bytes(m, n, k, bm, bn, ab, bb, ob, beta,
                                     extra_mn_inputs, density)
     flops = int(2 * m * n * k * density)

@@ -22,6 +22,29 @@ _state = threading.local()
 
 _VALID = ("auto", "pallas", "interpret", "xla")
 
+# The checkout root (src/repro/core/config.py -> three levels up).
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a fixed directory.
+
+    Entry points (``launch/serve.py``, ``launch/train.py``,
+    ``chip_smoke.py``, ``benchmarks/run.py``) call this once before they
+    compile; importing the package never does.  A set
+    ``JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set (JAX reads
+    the variable itself).  Otherwise the cache lives in
+    ``<checkout>/.jax_cache``: a fixed path, since the path is part of what
+    a later run looks up.  Returns the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 def _default_backend() -> str:
     env = os.environ.get("REPRO_GEMM_BACKEND", "auto")

@@ -135,8 +135,7 @@ def _apply_gemm(x, w, bias, extras, spec: GemmSpec, epilogue: EpilogueSpec,
       dequant into the consuming GEMM read.
     * The compute-dtype down-cast is pinned shard-local BEFORE any
       FSDP/EP all-gather: without the barrier GSPMD gathers the f32 master
-      weights and converts after, doubling gather wire bytes (measured on
-      mixtral train_4k — EXPERIMENTS.md §Perf).  Safe under
+      weights and converts after, doubling gather wire bytes.  Safe under
       differentiation: it only ever runs inside the custom-VJP core, where
       JAX never needs a JVP rule for the barrier.
     * ``acc_dtype`` overrides the accumulator/partial-sum dtype on the XLA

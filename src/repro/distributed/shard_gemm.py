@@ -64,7 +64,6 @@ from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -350,9 +349,9 @@ def mp_dot_sharded(
         def body(xl, bl, biasl):
             return mp_dot(xl, bl, biasl, **kw)
 
-        f = shard_map(body, mesh,
-                      in_specs=(P(None, None), P(None, axis), P(axis)),
-                      out_specs=P(None, axis), check_rep=False)
+        f = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P(None, None), P(None, axis), P(axis)),
+                          out_specs=P(None, axis), check_vma=False)
         with mesh_namespace(tag):
             return f(x, b, _bias_or_empty(bias, b.shape[1]))
 
@@ -376,9 +375,9 @@ def mp_dot_sharded(
             def body(xl, bl):
                 return jax.lax.psum(dot(xl, bl), axis)
             out_spec = P(None, None)
-        f = shard_map(body, mesh,
-                      in_specs=(P(None, axis), P(axis, None)),
-                      out_specs=out_spec, check_rep=False)
+        f = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P(None, axis), P(axis, None)),
+                          out_specs=out_spec, check_vma=False)
         with mesh_namespace(tag):
             y = f(x, b)
         return _finish(y, bias, out_dtype, policy)
@@ -397,9 +396,9 @@ def mp_dot_sharded(
         def body(xl, bl):
             full = jax.lax.all_gather(xl, axis, axis=0, tiled=True)
             return dot(full, bl)
-    f = shard_map(body, mesh,
-                  in_specs=(P(axis, None), P(None, axis)),
-                  out_specs=P(None, axis), check_rep=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P(axis, None), P(None, axis)),
+                      out_specs=P(None, axis), check_vma=False)
     with mesh_namespace(tag):
         y = f(x, b)
     return _finish(y, bias, out_dtype, policy)
@@ -499,11 +498,11 @@ def mp_dot_grouped_sharded(
         if bias_full.ndim == 1:
             bias_full = jnp.broadcast_to(bias_full[None, :],
                                          (g, bias_full.shape[0]))
-        f = shard_map(
-            body, mesh,
+        f = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P(None, axis, None), P(axis, None, None),
                       P(axis, None)),
-            out_specs=P(None, axis, None), check_rep=False)
+            out_specs=P(None, axis, None), check_vma=False)
         with mesh_namespace(tag):
             y = f(x, b, bias_full)
 

@@ -1,6 +1,6 @@
 """Logical sharding rules -> NamedSharding for every param / input / cache.
 
-Scheme (DESIGN.md §3):
+Scheme:
   * 'model' axis: tensor parallelism — d_ff, attention-head projections,
     vocab dim of embed/head, expert dim (EP) when divisible, KV-cache
     sequence dim (sequence-parallel decode).

@@ -19,13 +19,13 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# Grid (b, kv_head, group, q_block, kv_block): the KV walk is the revisited
+# reduction axis, every other axis is independent.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel",) * 4 + ("arbitrary",))
 
 
 def _flash_kernel(
@@ -111,19 +111,6 @@ def flash_attention(
         _flash_kernel, nk=nk, bq=bq, bk=bk, tq=tq, tk=tk,
         causal=causal, window=window, scale=scale, kv_rem=tk % bk,
     )
-    kwargs = {}
-    if not interpret and pltpu is not None:
-        cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams", None
-        )
-        if cls is not None:
-            try:
-                kwargs["compiler_params"] = cls(
-                    dimension_semantics=("parallel",) * 4 + ("arbitrary",)
-                )
-            except Exception:  # pragma: no cover
-                pass
-
     out5 = pl.pallas_call(
         kernel,
         grid=grid,
@@ -136,17 +123,13 @@ def flash_attention(
             (1, 1, 1, bq, d), lambda b_, h_, g_, i, j: (b_, h_, g_, i, 0)
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, tq, d), q.dtype),
-        scratch_shapes=(
-            [
-                pltpu.VMEM((bq, 128), jnp.float32),
-                pltpu.VMEM((bq, 128), jnp.float32),
-                pltpu.VMEM((bq, d), jnp.float32),
-            ]
-            if pltpu
-            else []
-        ),
+        scratch_shapes=[
+            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
+        ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        **kwargs,
     )(q5, k, v)
     return out5.reshape(b, h, tq, d)
 
@@ -250,11 +233,6 @@ def paged_flash_attention(
     bq = min(block_q, max(8, tq))
     nq = pl.cdiv(tq, bq)
 
-    if pltpu is None:  # pragma: no cover
-        raise NotImplementedError(
-            "paged_flash_attention needs pallas TPU scalar prefetch; use "
-            "models.attention.paged_attention_ref on this backend")
-
     q5 = q.reshape(b, hkv, g, tq, d)
     grid = (b, hkv, g, nq, w)
     kernel = functools.partial(
@@ -288,25 +266,12 @@ def paged_flash_attention(
             pltpu.VMEM((bq, d), jnp.float32),
         ],
     )
-    kwargs = {}
-    if not interpret:
-        cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams", None
-        )
-        if cls is not None:
-            try:
-                kwargs["compiler_params"] = cls(
-                    dimension_semantics=("parallel",) * 4 + ("arbitrary",)
-                )
-            except Exception:  # pragma: no cover
-                pass
-
     out5 = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, tq, d), q.dtype),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        **kwargs,
     )(
         block_tables.reshape(-1).astype(jnp.int32),
         q_start.astype(jnp.int32),

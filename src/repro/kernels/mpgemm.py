@@ -34,11 +34,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu imports fine on CPU installs; guard anyway.
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs
 from repro.core.blocking import (
@@ -87,9 +83,13 @@ def resolve_b_operand(
 
 
 def _mask_contract(x, axis: int, valid):
-    """Zero out lanes >= ``valid`` along ``axis`` (edge predication)."""
+    """Zero out lanes >= ``valid`` along ``axis`` (edge predication).
+
+    The select runs in f32 (exact for every operand dtype here): Mosaic
+    cannot select on packed 8/16-bit tiles with fewer rows than one
+    sublane tile, which decode-sized blocks have."""
     idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
-    return jnp.where(idx < valid, x, jnp.zeros_like(x))
+    return jnp.where(idx < valid, x.astype(jnp.float32), 0.0).astype(x.dtype)
 
 
 def _dot_dims(trans_a: bool, trans_b: bool):
@@ -212,7 +212,7 @@ def make_gemm_kernel(*, spec: GemmSpec, epilogue: EpilogueSpec, nk: int,
             valid = jnp.where(kk_ref[t] == nk - 1, k_rem,
                               a.shape[0 if spec.trans_a else 1])
             a = _mask_contract(a, 0 if spec.trans_a else 1, valid)
-        ts = ts_ref[0, 0] if spec.tile_scaled else None
+        ts = ts_ref[0] if spec.tile_scaled else None
         _accumulate(acc_ref, a, b, ts, spec.trans_a, False, acc_dtype)
 
         @pl.when(last_ref[t] == 1)
@@ -285,20 +285,10 @@ def make_gemm_kernel(*, spec: GemmSpec, epilogue: EpilogueSpec, nk: int,
     return kernel
 
 
-def _compiler_params(interpret: bool, grid_rank: int = 3):
+def _compiler_params(grid_rank: int):
     """Grid semantics: every axis parallel except the K-innermost one."""
-    if interpret or pltpu is None:
-        return None
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cls is None:
-        return None
-    semantics = ("parallel",) * (grid_rank - 1) + ("arbitrary",)
-    try:
-        return cls(dimension_semantics=semantics)
-    except Exception:  # pragma: no cover
-        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (grid_rank - 1) + ("arbitrary",))
 
 
 def _layout_plan(m: int, k: int, n: int, layout, a_dtype, out_dtype,
@@ -361,8 +351,7 @@ def _bias_input(bias, grouped: bool, g: int, n: int):
 
 def _scale_spec_and_input(scale, interpret: bool):
     """The dynamic-quant per-tensor scale rides SMEM (1-elem f32)."""
-    spec = pl.BlockSpec(
-        memory_space=pltpu.SMEM if (pltpu and not interpret) else None)
+    spec = pl.BlockSpec(memory_space=None if interpret else pltpu.SMEM)
     return spec, jnp.asarray(scale, jnp.float32).reshape(1)
 
 
@@ -391,9 +380,6 @@ def _launch_sparse(a, b_sparse: TileSparseOperand, *, c, bias, scale, extras,
     columns get one anchor visit of the shared zero payload tile so their
     epilogue (bias/activation/residual/beta·C) still runs.
     """
-    if pltpu is None:  # pragma: no cover - CPU jaxlibs ship pltpu
-        raise NotImplementedError(
-            "tile-sparse launches need pallas.tpu (PrefetchScalarGridSpec)")
     layout = b_sparse.layout
     grouped = spec.grouped
     sched = build_schedule(layout)
@@ -431,8 +417,8 @@ def _launch_sparse(a, b_sparse: TileSparseOperand, *, c, bias, scale, extras,
     inputs = [a, b_sparse.payload]
     if spec.tile_scaled:
         in_specs.append(pl.BlockSpec(
-            (1, 1), _sim(lambda i, t, kk, jj, slot, gg: (slot[t], 0))))
-        inputs.append(b_sparse.scales)
+            (1, 1, 1), _sim(lambda i, t, kk, jj, slot, gg: (slot[t], 0, 0))))
+        inputs.append(b_sparse.scales[..., None])
     mn_spec = pl.BlockSpec(
         lead + (bm, bn),
         _sim(lambda i, t, kk, jj, slot, gg: _lead(gg, t) + (i, jj[t])))
@@ -467,10 +453,6 @@ def _launch_sparse(a, b_sparse: TileSparseOperand, *, c, bias, scale, extras,
         out_specs=mn_spec,
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
     )
-    kwargs = {}
-    params = _compiler_params(interpret, grid_rank=len(grid))
-    if params is not None:
-        kwargs["compiler_params"] = params
     sp_args = [jnp.asarray(x) for x in
                (sched.kk, sched.jj, sched.slot, sched.first, sched.last)]
     if grouped:
@@ -480,8 +462,8 @@ def _launch_sparse(a, b_sparse: TileSparseOperand, *, c, bias, scale, extras,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
+        compiler_params=_compiler_params(len(grid)),
         interpret=interpret,
-        **kwargs,
     )(*sp_args, *inputs)
 
 
@@ -688,9 +670,11 @@ def mpgemm_pallas_spec(
         inputs = [a, b]
     in_specs = [a_spec, b_spec]
     if spec.tile_scaled:
-        in_specs.append(pl.BlockSpec(lead + (1, 1),
-                                     _im(lambda i, j, kk: (kk, j))))
-        inputs.append(b_packed.scales)
+        # One scale per (kk, j) tile, carried as a trailing (1, 1) so the
+        # block's minor dims equal the array's (Mosaic's tiling rule).
+        in_specs.append(pl.BlockSpec(lead + (1, 1, 1, 1),
+                                     _im(lambda i, j, kk: (kk, j, 0, 0))))
+        inputs.append(b_packed.scales[..., None, None])
     mn_spec = pl.BlockSpec(lead + (bm, bn), _im(lambda i, j, kk: (i, j)))
     if epilogue.beta != 0.0:
         in_specs.append(mn_spec)
@@ -708,10 +692,6 @@ def mpgemm_pallas_spec(
         in_specs.append(row_spec if nm in ep_def.row_operands else mn_spec)
         inputs.append(x)
 
-    scratch = [pltpu.VMEM((bm, bn), acc_dtype)] if pltpu else [
-        pl.BlockSpec(memory_space=pl.ANY)
-    ]
-
     codec = layout.codec if layout is not None else None
     sub_byte = codec is not None and codec.elems_per_byte > 1
     kernel = make_gemm_kernel(
@@ -724,11 +704,6 @@ def mpgemm_pallas_spec(
         b_rows=layout.bk if sub_byte else None,
     )
 
-    kwargs = {}
-    params = _compiler_params(interpret, grid_rank=len(grid))
-    if params is not None:
-        kwargs["compiler_params"] = params
-
     out_shape = ((g, m, n) if grouped else (m, n))
     with obs.span("gemm.launch", bytes=plan.hbm_bytes, flops=plan.flops,
                   m=m, n=n, k=k, g=g, grid=str(grid), **launch_labels):
@@ -738,9 +713,9 @@ def mpgemm_pallas_spec(
             in_specs=in_specs,
             out_specs=mn_spec,
             out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
-            scratch_shapes=scratch,
+            scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+            compiler_params=_compiler_params(len(grid)),
             interpret=interpret,
-            **kwargs,
         )(*inputs)
 
 

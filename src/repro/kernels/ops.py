@@ -32,7 +32,7 @@ def _note_fallback(op: str, reason: str) -> None:
 
 
 def flash_attention_fallback_reason(
-    q_dtype, k_dtype, v_dtype, *, interpret: bool, backend: str,
+    q_dtype, k_dtype, v_dtype, *, backend: str,
 ) -> Optional[str]:
     """Why :func:`flash_attention` will trace the XLA reference instead of
     the Pallas kernel — None means the kernel path is taken.
@@ -47,10 +47,6 @@ def flash_attention_fallback_reason(
         if not jnp.issubdtype(jnp.dtype(dt), jnp.floating):
             return (f"non-float {name} dtype {jnp.dtype(dt).name} "
                     "(the kernel's online softmax needs float operands)")
-    if not interpret:
-        from repro.kernels import flash_attention as _fa_mod
-        if _fa_mod.pltpu is None:
-            return "Pallas TPU backend unavailable and interpret=False"
     return None
 
 
@@ -108,17 +104,22 @@ def flash_attention(
     """Blocked online-softmax attention; q (B,H,Tq,D), k/v (B,Hkv,Tk,D).
 
     Dispatch is explicit: :func:`flash_attention_fallback_reason` decides
-    whether this call traces the Pallas kernel or the XLA reference, and an
-    IMPLICIT fallback (anything other than ``backend="xla"``) is logged
-    once per process.
+    whether this call traces the Pallas kernel or the XLA reference.  An
+    IMPLICIT fallback (anything other than ``backend="xla"``) is an error
+    on the compiled TPU path, where it would hide the device behind the
+    reference; under ``interpret=True`` it is logged once per process.
     """
     if q.shape[1] % k.shape[1]:
         raise ValueError(
             f"GQA requires H % Hkv == 0, got {q.shape[1]} % {k.shape[1]}")
     reason = flash_attention_fallback_reason(
-        q.dtype, k.dtype, v.dtype, interpret=interpret, backend=backend)
+        q.dtype, k.dtype, v.dtype, backend=backend)
     if reason is not None:
         if backend != "xla":
+            if not interpret:
+                raise ValueError(
+                    f"flash_attention: the Pallas TPU kernel cannot run "
+                    f"({reason}); pass backend='xla' for the reference")
             _note_fallback("flash_attention", reason)
         kr = jnp.repeat(k, q.shape[1] // k.shape[1], axis=1)
         vr = jnp.repeat(v, q.shape[1] // v.shape[1], axis=1)

@@ -9,20 +9,6 @@ from __future__ import annotations
 import jax
 
 
-def _axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` for ``jax.make_mesh``, version-guarded.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types`` parameter) landed
-    after the pinned jax 0.4.37; on older jax every mesh axis already
-    behaves as ``Auto``, so omitting the argument is semantically
-    identical — the guard only skips spelling out the default.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def _mesh(shape, axes):
     import numpy as np
     n = int(np.prod(shape))
@@ -32,7 +18,7 @@ def _mesh(shape, axes):
             f"mesh {shape} needs {n} devices, have {len(devices)} "
             "(dry-runs must set xla_force_host_platform_device_count first)")
     return jax.make_mesh(shape, axes, devices=devices,
-                         **_axis_types_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -17,11 +17,12 @@ import numpy as np
 import jax
 
 from repro.configs import base as cb
+from repro.core import config
 from repro.models.transformer import build_model
 from repro.serve.engine import Request, ServeEngine
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-moe-1b-a400m",
                     choices=cb.ARCH_IDS)
@@ -98,27 +99,27 @@ def main():
                     help="record obs tracing spans (serve step phases, "
                          "GEMM plan/pack/launch legs) and write a "
                          "Perfetto/chrome://tracing trace.json to FILE")
-    args = ap.parse_args()
+    return ap
 
-    tracer = None
-    if args.trace_out:
-        from repro.obs import trace as obs_trace
-        tracer = obs_trace.Tracer()
-        obs_trace.set_tracer(tracer)
-    server = None
-    if args.metrics_port is not None:
-        from repro.obs.server import start_metrics_server
-        server = start_metrics_server(port=args.metrics_port)
-        print(f"[serve] metrics server on {server.url} "
-              f"(/metrics, /metrics.json, /trace)")
 
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse the CLI; folds the deprecated ``--batch`` into ``--max-batch``."""
+    args = build_parser().parse_args(argv)
     if args.batch is not None:
         print("[serve] --batch is deprecated; use --max-batch "
               "(and --engine wave for the legacy wave engine)")
         if args.max_batch is None:
             args.max_batch = args.batch
-    max_batch = args.max_batch if args.max_batch is not None else 2
+    if args.max_batch is None:
+        args.max_batch = 2
+    return args
 
+
+def load_model(args):
+    """Config, model and (packed / tile-sparse) parameters for ``args``.
+
+    -> (cfg, model, params).  ``chip_smoke.py`` builds its models here too.
+    """
     if args.no_fuse:
         # Read lazily at trace time by models/layers.py via
         # core.config.fused_epilogues(), so setting it before build works.
@@ -139,7 +140,7 @@ def main():
     if args.pack:
         from repro.packing import pack_params, packed_param_bytes
         params = pack_params(params, policy=args.policy,
-                             m_hint=max_batch * 32,
+                             m_hint=args.max_batch * 32,
                              pack_format=args.pack_format)
         fmt = f", format={args.pack_format}" if args.pack_format else ""
         print(f"[serve] packed static weights: "
@@ -162,7 +163,8 @@ def main():
                                  method=args.sparsity_method,
                                  nm=(n_keep, m_block),
                                  blocks=args.sparsity_blocks,
-                                 policy=args.policy, m_hint=max_batch * 32)
+                                 policy=args.policy,
+                                 m_hint=args.max_batch * 32)
         density = sparse_param_density(params)
         print(f"[serve] tile-sparse static weights: "
               f"{sparse_param_bytes(params)/2**20:.1f} MiB payload, "
@@ -174,18 +176,43 @@ def main():
                   f"weight shapes (pruning is per whole tile). Pass "
                   f"--sparsity-blocks with smaller BK BN for finer "
                   f"granularity.")
+    return cfg, model, params
+
+
+def make_engine(args, model, params) -> ServeEngine:
+    """The serving engine ``args`` selects over ``model``/``params``."""
     if args.engine == "wave":
         with warnings.catch_warnings():
             # The CLI chose the wave engine explicitly; the constructor's
             # deprecation warning targets programmatic batch_size= callers.
             warnings.simplefilter("ignore", DeprecationWarning)
-            eng = ServeEngine(model, params, batch_size=max_batch,
+            eng = ServeEngine(model, params, batch_size=args.max_batch,
                               max_len=args.max_len)
     else:
         eng = ServeEngine(model, params, max_len=args.max_len,
-                          max_batch=max_batch, page_size=args.page_size,
+                          max_batch=args.max_batch, page_size=args.page_size,
                           max_pages=args.max_pages,
                           prefill_chunk=args.prefill_chunk)
+    return eng
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    config.use_compile_cache()
+    tracer = None
+    if args.trace_out:
+        from repro.obs import trace as obs_trace
+        tracer = obs_trace.Tracer()
+        obs_trace.set_tracer(tracer)
+    server = None
+    if args.metrics_port is not None:
+        from repro.obs.server import start_metrics_server
+        server = start_metrics_server(port=args.metrics_port)
+        print(f"[serve] metrics server on {server.url} "
+              f"(/metrics, /metrics.json, /trace)")
+
+    cfg, model, params = load_model(args)
+    eng = make_engine(args, model, params)
     rng = np.random.default_rng(0)
     warm = None
     if args.warm_prefix > 0:
@@ -216,8 +243,10 @@ def main():
     out = eng.generate(reqs)
     dt = time.time() - t0
     n_tok = sum(len(v) for v in out.values())
+    dev = jax.devices()[0]
     print(f"[serve] {args.requests} requests, {n_tok} tokens, {dt:.1f}s "
-          f"({n_tok/dt:.1f} tok/s CPU, policy={args.policy})")
+          f"wall ({n_tok/dt:.1f} tok/s on {dev.platform} "
+          f"{dev.device_kind}, policy={args.policy})")
     if args.engine == "wave":
         for t in eng.telemetry:
             print(f"  wave{t.wave}: {t.requests} reqs, {t.tokens} tok, "

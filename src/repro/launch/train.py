@@ -11,6 +11,7 @@ import jax
 
 from repro.configs import base as cb
 from repro.configs.base import ShapeConfig
+from repro.core import config
 from repro.models.transformer import build_model
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    config.use_compile_cache()
 
     cfg = cb.get(args.arch, smoke=args.smoke)
     model = build_model(cfg, policy=args.policy)

@@ -34,11 +34,10 @@ def _pad_heads_for_tp(q, k, v):
 
     When H does not divide the TP axis (phi3-medium: 40 heads on a 16-wide
     axis), the divisibility guard would REPLICATE attention across the axis
-    — 16x the flops and logits traffic per device (measured: phi3-medium
-    prefill_32k memory term 20.3s vs 1.7s compute).  Padding to the next
-    multiple (40->48) costs 20% padded compute but shards 16 ways: ~13x net
-    reduction.  K/V are expanded to full MHA first so padded q heads pair
-    with zero K/V (softmax over zero logits -> zero output, sliced off).
+    — 16x the flops and logits traffic per device.  Padding to the next
+    multiple (40->48) costs 20% padded compute but shards 16 ways.  K/V
+    are expanded to full MHA first so padded q heads pair with zero K/V
+    (softmax over zero logits -> zero output, sliced off).
     Returns (q, k, v, original_h)."""
     h = q.shape[1]
     mesh = act.current_mesh()
@@ -152,10 +151,8 @@ def chunked_attention(
     if causal and nq > 1 and tq == tk:
         # TRIANGULAR block schedule: only (i, j<=i) pairs are visited, so
         # the ~half of blocks that the causal mask fully kills never load,
-        # compute, or spill logits (48% of attention HBM traffic at nq=32;
-        # EXPERIMENTS.md §Perf, phi3-medium hillclimb iteration 2).
-        # Strictly-below-diagonal pairs additionally skip the mask select
-        # pass (iteration 3) when chunk sizes allow.
+        # compute, or spill logits.  Strictly-below-diagonal pairs
+        # additionally skip the mask select pass when chunk sizes allow.
         maskless_ok = (q_chunk == kv_chunk)
 
         def make_step(need_mask):
@@ -315,10 +312,6 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window=None, scale=None):
     return o.reshape(b, h, 1, d).astype(q.dtype)
 
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.7 name
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -360,7 +353,7 @@ def flash_decode_sharded(q, k_cache, v_cache, k_new, v_new, pos, mesh,
         # Keep cache operands in their stored bf16 and accumulate f32 via
         # preferred_element_type: casting the cache slice to f32 here makes
         # XLA maintain a full f32 shadow of the stacked cache in the layer
-        # scan carry (measured +30 GB/step; EXPERIMENTS.md §Perf).
+        # scan carry.
         qg = q.reshape(bl, hkv, g, d).astype(kc.dtype)
         logits = jnp.einsum("bhgd,bhkd->bhgk", qg, kc,
                             preferred_element_type=jnp.float32) * sm
@@ -381,7 +374,7 @@ def flash_decode_sharded(q, k_cache, v_cache, k_new, v_new, pos, mesh,
     bspec = da if len(da) > 1 else da[0]
     qs = P(bspec, None, None, None)
     cs = P(bspec, None, "model", None)
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(qs, cs, cs, qs, qs, P()),
         out_specs=(qs, cs, cs),

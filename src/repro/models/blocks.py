@@ -152,8 +152,8 @@ def attn_decode(params, x, cache, ctx):
         k = apply_rope(k, cos, sin, pidx)
     mesh = act.current_mesh()
     if mesh is not None and attn.can_flash_decode(q, cache["k"], mesh):
-        # Sequence-parallel flash decode (EXPERIMENTS.md §Perf hillclimb 2):
-        # cond-guarded local ring write + LSE psum combine.
+        # Sequence-parallel flash decode: cond-guarded local ring write +
+        # LSE psum combine.
         o, kc, vc = attn.flash_decode_sharded(
             q, cache["k"], cache["v"], k, v, pos, mesh)
     else:
@@ -314,8 +314,7 @@ def _expert_dot(ebuf, w, policy, **fusion):
     between the expert GEMMs and the combine).  The op's custom VJP runs
     the backward contractions as fused-transpose grouped GEMMs with bf16
     partial sums on the XLA backend, so the dbuf/dW EP/TP all-reduces move
-    bf16 on the wire (the mixtral-hillclimb optimization that einsum-based
-    dispatch could not express — see EXPERIMENTS.md §Perf).
+    bf16 on the wire (which einsum-based dispatch could not express).
 
     ``fusion`` forwards registry-epilogue operands (``activation=``,
     ``gate=`` — core/gemm_spec.py), which is how the MoE SwiGLU gating

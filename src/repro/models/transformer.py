@@ -150,6 +150,27 @@ class LM:
         return ((self.cfg.vocab + 127) // 128) * 128
 
     def init(self, key) -> Dict[str, Any]:
+        """Parameters in the policy's storage dtype (``param_dtype``).
+
+        f32 storage (fp32, bf16 with f32 masters) builds the tree as is.
+        A narrower storage dtype casts every float leaf inside ONE jitted
+        program, so each leaf leaves the device already narrowed and no
+        f32 copy of the stack ever exists (phi3-mini is 15 GB in f32).
+        """
+        from repro.core.policy import get_policy
+        dtype = jnp.dtype(get_policy(self.policy).param_dtype)
+        if dtype == jnp.float32:
+            return self._init_f32(key)
+
+        def narrowed(k):
+            return jax.tree_util.tree_map(
+                lambda a: a.astype(dtype)
+                if jnp.issubdtype(a.dtype, jnp.floating) else a,
+                self._init_f32(k))
+
+        return jax.jit(narrowed)(key)
+
+    def _init_f32(self, key) -> Dict[str, Any]:
         cfg = self.cfg
         vp = self.vocab_padded
         keys = jax.random.split(key, 8)
@@ -241,12 +262,10 @@ class LM:
             caches = []
             for p, kind in enumerate(unit):
                 x, a, c = block_fwd(kind, unit_params[p], x, ctx)
-                # NOTE(perf-log H1, refuted): constraining x to
-                # ('batch','model',None) here (sequence-parallel residual)
-                # made the collective term 4.7x WORSE under GSPMD — it
-                # reshards around every block-internal op instead of
-                # forming reduce-scatter/all-gather pairs.  See
-                # EXPERIMENTS.md §Perf.
+                # Constraining x to ('batch','model',None) here (a
+                # sequence-parallel residual) makes GSPMD reshard around
+                # every block-internal op instead of forming
+                # reduce-scatter/all-gather pairs.
                 x = act.constrain(x, "batch", None, None)
                 aux = aux + a
                 caches.append(c)

@@ -109,12 +109,15 @@ def pack_nibbles(q):
 def unpack_nibbles(b, rows: int):
     """Inverse of :func:`pack_nibbles` — the in-register decode the GEMM
     kernel uses: sign-extend each nibble with arithmetic shifts, then
-    interleave back to ``rows`` logical K rows."""
-    lo = (b << 4) >> 4                    # int8 shifts sign-extend
-    hi = b >> 4
+    interleave back to ``rows`` logical K rows.  The shifts run on int32
+    (Mosaic has no int8 vector shifts); arithmetic right shifts
+    sign-extend."""
+    b32 = b.astype(jnp.int32)
+    lo = (b32 << 28) >> 28
+    hi = b32 >> 4
     pair = jnp.stack((lo, hi), axis=-2)   # (..., hk, 2, bn)
     full = pair.reshape(*b.shape[:-2], 2 * b.shape[-2], b.shape[-1])
-    return full[..., :rows, :]
+    return full[..., :rows, :].astype(jnp.int8)
 
 
 def _encode_quant_tiles(tiles, codec):

@@ -33,8 +33,7 @@ def make_train_step(model, opt_cfg: AdamWConfig, microbatches: int = 1,
 
     ``grad_shardings`` (a params-like tree of NamedShardings) pins each
     microbatch's gradients to the parameter sharding, so GSPMD emits
-    reduce-scatters into the shards instead of full-tensor all-reduces
-    (EXPERIMENTS.md §Perf, mixtral hillclimb)."""
+    reduce-scatters into the shards instead of full-tensor all-reduces."""
 
     def loss_fn(params, mb):
         return model.loss_fn(params, mb)

@@ -132,7 +132,7 @@ def test_ops_flash_attention_path_traced(rng, caplog):
     ki = jnp.zeros(k.shape, jnp.int32)
     vi = jnp.zeros(v.shape, jnp.int32)
     reason = ops.flash_attention_fallback_reason(
-        qi.dtype, ki.dtype, vi.dtype, interpret=True, backend="pallas")
+        qi.dtype, ki.dtype, vi.dtype, backend="pallas")
     assert reason is not None and "non-float" in reason
     with caplog.at_level(logging.WARNING, logger="repro.kernels.ops"):
         assert not _traced_pallas_call(ops.flash_attention, qi, ki, vi,
@@ -141,6 +141,20 @@ def test_ops_flash_attention_path_traced(rng, caplog):
                                        interpret=True, causal=False)
     fallback_logs = [r for r in caplog.records if "reference path" in r.message]
     assert len(fallback_logs) == 1  # logged once, later fallbacks silent
+
+
+def test_ops_flash_attention_compiled_path_refuses_fallback(rng):
+    """On the compiled (TPU) path an implicit reference fallback would hide
+    the device behind XLA: it is an error, not a log line."""
+    from repro.kernels import ops
+
+    q, k, v = _qkv(rng, 1, 4, 2, 16, 16, 64)
+    qi = jnp.zeros(q.shape, jnp.int32)
+    with pytest.raises(ValueError, match="backend='xla'"):
+        ops.flash_attention(qi, k, v)
+    # The explicit reference route stays open.
+    assert not _traced_pallas_call(ops.flash_attention, qi, k, v,
+                                   backend="xla")
 
 
 def test_ops_flash_attention_fallback_matches_kernel(rng):

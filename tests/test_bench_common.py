@@ -10,8 +10,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from benchmarks import common
 
 # Paper Table III, verbatim: 18 DeepSeek shapes (IDs 1-18, M in
-# {64, 128, 4096}) + 6 LLaMA shapes (IDs 19-24).  The benchmarks, the
-# committed baselines, and EXPERIMENTS.md all cite these 24 rows — a silent
+# {64, 128, 4096}) + 6 LLaMA shapes (IDs 19-24).  The benchmarks and the
+# committed baselines all cite these 24 rows — a silent
 # edit here would invalidate every downstream number, so the set is pinned
 # exactly.
 TABLE_III = [

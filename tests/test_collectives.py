@@ -7,7 +7,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.collectives import (
@@ -55,10 +54,10 @@ def test_compressed_psum_matches_sum_oracle(rng):
 
     @jax.jit
     def run(gg, ee):
-        return shard_map(
+        return jax.shard_map(
             lambda gl, el: compressed_psum(gl, el, "data"),
             mesh=mesh, in_specs=(P("data"), P("data")),
-            out_specs=(P("data"), P("data")), check_rep=False)(gg, ee)
+            out_specs=(P("data"), P("data")), check_vma=False)(gg, ee)
 
     total, new_err = run(g, err)
     # Every shard's reduced value is the sum of ALL shards' dequantized
@@ -83,10 +82,10 @@ def test_hierarchical_all_reduce_matches_total_sum(rng):
 
     @jax.jit
     def run(xx):
-        return shard_map(
+        return jax.shard_map(
             lambda xl: hierarchical_all_reduce(xl),
             mesh=mesh, in_specs=(P("pod", "data"),),
-            out_specs=P("pod", "data"), check_rep=False)(xx)
+            out_specs=P("pod", "data"), check_vma=False)(xx)
 
     got = np.asarray(run(x))
     # reduce-scatter in-pod + all-reduce cross-pod + all-gather in-pod ==

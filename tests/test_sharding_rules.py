@@ -93,7 +93,9 @@ def test_sharded_execution_8dev_inprocess():
 def test_sharded_execution_8dev_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    # The parent holds the process's accelerator (if any); the child gets
+    # its eight devices from the host platform and never asks for a chip.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
     child = inspect.getsource(_sharded_check) + "\n_sharded_check()\n"
