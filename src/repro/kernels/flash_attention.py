@@ -270,6 +270,7 @@ def paged_flash_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, tq, d), q.dtype),
+        name="paged_flash_attention",
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(

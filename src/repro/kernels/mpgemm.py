@@ -462,6 +462,7 @@ def _launch_sparse(a, b_sparse: TileSparseOperand, *, c, bias, scale, extras,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
+        name="mpgemm_grouped" if grouped else "mpgemm",
         compiler_params=_compiler_params(len(grid)),
         interpret=interpret,
     )(*sp_args, *inputs)
@@ -714,6 +715,7 @@ def mpgemm_pallas_spec(
             out_specs=mn_spec,
             out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+            name="mpgemm_grouped" if grouped else "mpgemm",
             compiler_params=_compiler_params(len(grid)),
             interpret=interpret,
         )(*inputs)

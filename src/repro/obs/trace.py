@@ -1,19 +1,28 @@
-"""Structured tracing spans with Chrome/Perfetto ``trace.json`` export.
+"""Structured tracing spans, on the JAX profiler's clock and in a
+Chrome/Perfetto ``trace.json``.
 
 ``span("pack", bytes=...)`` opens a named region on the current thread;
-spans nest through a contextvar, so a serve step renders as a real
-timeline (``serve.step`` ⊃ ``serve.admit`` ⊃ ``gemm.launch`` …) when the
-exported file is loaded into Perfetto / ``chrome://tracing``.
+spans nest through a contextvar.  Each engine step renders as
+``serve.step`` ⊃ ``serve.admit``, ``serve.schedule``, ``serve.dispatch``,
+``serve.sync``, ``serve.commit``, ``serve.telemetry``; the GEMM spans
+(``gemm.plan`` ⊃ ``gemm.launch``, ``pack``, ``tune``) fire while jit
+traces a program, not per step.
 
-Timestamps are host-side (``perf_counter_ns`` relative to tracer start);
-modeled bytes/FLOPs from the GemmPlan ride along as span args — on CPU
-the wall clocks are noise but the modeled terms localize where traffic
-goes, which is the paper's Section 3 methodology applied at runtime.
+Every span is recorded twice while a tracer is installed:
+
+* in the tracer's memory, host-side (``perf_counter_ns`` relative to
+  tracer start), for ``launch/serve.py --trace-out`` and ``/trace``;
+* as a ``jax.profiler.TraceAnnotation`` of the same name whose args are
+  event stats, so a ``jax.profiler`` trace holds it on the host plane,
+  on one clock with the device's ops.  Outside a profiler trace the
+  annotation records nothing.
 
 Tracing is OFF by default (the ambient tracer is None and the module
-helpers are no-ops); ``launch/serve.py --trace-out`` or ``set_tracer``
-turn it on.  Events accumulate in memory — the tracer is a recorder for
-bounded runs (a serve smoke, a bench), not a streaming profiler.
+helpers are no-ops: ``span`` returns one shared ``nullcontext``);
+``launch/serve.py --trace-out`` or ``set_tracer`` turn it on.  Events
+accumulate in memory — the tracer is a recorder for bounded runs (a
+serve smoke, a bench), not a streaming profiler.  jax is imported on
+the first span, so importing this module stays stdlib-only.
 """
 from __future__ import annotations
 
@@ -66,13 +75,18 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, category: str = "repro", **args: Any):
+        from jax.profiler import TraceAnnotation
+        annotation = TraceAnnotation(name, **args)
         rec = {"name": name, "cat": category, "args": dict(args),
-               "ts": self._now_us(), "tid": self._tid()}
+               "ts": self._now_us(), "tid": self._tid(),
+               "annotation": annotation}
         stack = _span_stack.get()
         token = _span_stack.set(stack + (rec,))
+        annotation.__enter__()
         try:
             yield rec
         finally:
+            annotation.__exit__(None, None, None)
             _span_stack.reset(token)
             dur = self._now_us() - rec["ts"]
             event = {"ph": "X", "name": name, "cat": category,
@@ -94,6 +108,7 @@ class Tracer:
         stack = _span_stack.get()
         if stack:
             stack[-1]["args"].update(args)
+            stack[-1]["annotation"].set_metadata(**args)
 
     # -- export ---------------------------------------------------------------
 

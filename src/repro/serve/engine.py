@@ -15,19 +15,27 @@ at a time, and when the pool runs dry the most recently admitted request
 is preempted (pages freed, request requeued for recompute) so older work
 keeps flowing — no head-of-line blocking, O(actual-length) KV memory.
 
-Every step emits a :class:`StepTelemetry` record (``engine.step_telemetry``,
-streamed through ``on_step``).  The old per-wave records survive as an
+Every step emits a :class:`StepTelemetry` record (``engine.step_telemetry``
+and the metrics registry).  The old per-wave records survive as an
 aggregation: :class:`WaveTelemetry` is built FROM the step records by the
 deprecated wave path (``batch_size=`` — a shim that keeps the original
 left-padded static-batch loop for archs without a paged path and for
 existing callers/tests).
+
+With a tracer installed (``repro.obs``), each continuous step is one
+``serve.step`` span over ``serve.admit``, ``serve.schedule`` (page
+reservation, host batch arrays), ``serve.dispatch`` (uploads and the
+jitted call, until it returns), ``serve.sync`` (waiting for the chip and
+copying the logits back), ``serve.commit`` (sampling, per-slot updates)
+and ``serve.telemetry``.  Each :class:`Request` carries host-clock stamps
+(``time.perf_counter()``) of its queueing, admission and first token.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Set
 
 import numpy as np
 
@@ -44,6 +52,11 @@ class Request:
     prompt: np.ndarray           # (T,) int32
     max_new_tokens: int = 16
     out_tokens: Optional[List[int]] = None
+    # Host-clock stamps (time.perf_counter()) set by the engine.
+    t_queued: Optional[float] = None       # add_request()
+    t_admitted: Optional[float] = None     # last admission into a slot
+    t_first_token: Optional[float] = None  # commit of the step sampling out[0]
+    preemptions: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,7 +153,6 @@ class ServeEngine:
                  max_pages: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
                  eos_id: int = 1, greedy: bool = True,
-                 on_step: Optional[Callable[[StepTelemetry], None]] = None,
                  on_wave: Optional[Callable[[WaveTelemetry], None]] = None,
                  batch_size: Optional[int] = None):
         self.model = model
@@ -148,7 +160,6 @@ class ServeEngine:
         self.max_len = max_len
         self.eos_id = eos_id
         self.greedy = greedy
-        self.on_step = on_step
         self.on_wave = on_wave
         self.telemetry: List[WaveTelemetry] = []
         self.step_telemetry: List[StepTelemetry] = []
@@ -184,6 +195,7 @@ class ServeEngine:
                               else max(page_size, 8))
         self.caches = model.init_paged_caches(self.max_pages, page_size)
         self._step_fn = jax.jit(model.paged_step)
+        self._chunks_dispatched: Set[int] = set()
         self._slots: List[Optional[_Slot]] = [None] * self.max_batch
         self._queue: deque = deque()
         self._admit_counter = 0
@@ -211,6 +223,9 @@ class ServeEngine:
             req = Request(uid=uid, prompt=np.asarray(prompt, np.int32),
                           max_new_tokens=max_new_tokens)
         self._uid_counter = max(self._uid_counter, req.uid + 1)
+        req.t_queued = time.perf_counter()
+        req.t_admitted = req.t_first_token = None
+        req.preemptions = 0
         if len(req.prompt) >= self.max_len:
             raise ValueError(f"prompt length {len(req.prompt)} >= max_len "
                              f"{self.max_len}")
@@ -223,8 +238,10 @@ class ServeEngine:
         self._queue.append(req)
         return req.uid
 
-    def _admit(self) -> None:
-        """FIFO admission into free slots while prompt pages fit."""
+    def _admit(self) -> int:
+        """FIFO admission into free slots while prompt pages fit; returns
+        how many requests were admitted."""
+        admitted = 0
         for i in range(self.max_batch):
             if not self._queue or self._slots[i] is not None:
                 continue
@@ -236,17 +253,21 @@ class ServeEngine:
                 self.kv.rollback_prefix_hits(len(shared_pages), shared_tokens)
                 break
             self._queue.popleft()
+            req.t_admitted = time.perf_counter()
             self._slots[i] = _Slot(
                 req=req, length=shared_tokens,
                 pending=np.asarray(req.prompt[shared_tokens:], np.int32),
                 next_token=None, out=[], admitted=self._admit_counter)
             self._admit_counter += 1
+            admitted += 1
+        return admitted
 
     def _evict_slot(self, i: int) -> None:
         """Preempt slot i: free its pages, requeue its request at the head
         (recompute semantics — generated tokens are discarded)."""
         s = self._slots[i]
         self.kv.free_seq(s.req.uid)
+        s.req.preemptions += 1
         self._queue.appendleft(s.req)
         self._slots[i] = None
         self._preempted_now += 1
@@ -276,11 +297,16 @@ class ServeEngine:
     def step(self) -> List[Request]:
         """Run one engine step; returns the requests that finished."""
         self._require_continuous("step()")
+        with obs.span("serve.step", step=self._step_counter):
+            return self._step()
+
+    def _step(self) -> List[Request]:
         t0 = time.perf_counter()
         self._preempted_now = 0
         with obs.span("serve.admit", step=self._step_counter,
                       queued=len(self._queue)):
-            self._admit()
+            admitted = self._admit()
+            obs.annotate(admitted=admitted)
         live = [s for s in self._slots if s is not None]
         if not live:
             if self._queue:
@@ -295,18 +321,57 @@ class ServeEngine:
         phase = ("prefill" if chunk and all(s.next_token is None
                                             for s in live)
                  else "mixed" if chunk else "decode")
-        # Reserve pages for this step's writes (may preempt).  _reserve()
-        # can evict any NEWER slot, so re-read liveness from self._slots
-        # each iteration — a snapshot would hand slots whose pages were
-        # just freed back to _reserve().
+        with obs.span("serve.schedule"):
+            tokens, q_start, n_valid, bt = self._schedule(c)
+            obs.annotate(preempted=self._preempted_now)
+        live = [s for s in self._slots if s is not None]
+        obs.annotate(phase=phase, live=len(live), chunk=c)
+        new_shape = c not in self._chunks_dispatched
+        with obs.span("serve.dispatch", new_shape=new_shape):
+            if new_shape:
+                self._chunks_dispatched.add(c)
+                obs.counter_inc("serve_new_step_shapes_total", chunk=c,
+                                help="first dispatch of each step width "
+                                     "(a compile or a compile-cache load)")
+            logits, self.caches = self._step_fn(
+                self.params, jnp.asarray(tokens), self.caches,
+                jnp.asarray(bt), jnp.asarray(q_start), jnp.asarray(n_valid))
+        with obs.span("serve.sync"):
+            logits = np.asarray(logits)   # blocks until device done
+        with obs.span("serve.commit"):
+            finished, emitted = self._commit(logits, n_valid)
+            obs.annotate(finished=len(finished))
+        with obs.span("serve.telemetry"):
+            wall = time.perf_counter() - t0
+            pages = self.kv.pages_in_use
+            rec = StepTelemetry(
+                step=self._step_counter, phase=phase, live=len(live),
+                queue_depth=len(self._queue), tokens=emitted,
+                preemptions=self._preempted_now, pages_in_use=pages,
+                page_occupancy=self.kv.occupancy,
+                kv_bytes=pages * self.page_size * self._token_bytes,
+                kv_bytes_dense=(self.max_batch * self.max_len
+                                * self._token_bytes),
+                prefix_hit_tokens=self.kv.stats.prefix_hit_tokens,
+                wall_s=wall,
+                tokens_per_s=emitted / wall if wall > 0 else 0.0)
+            self._emit_step(rec)
+        self._step_counter += 1
+        return finished
+
+    def _schedule(self, c: int):
+        """Reserve pages for this step's writes (may preempt) and build the
+        host batch: (tokens (B, c), q_start (B,), n_valid (B,), block
+        tables (B, W)).  _reserve() can evict any NEWER slot, so liveness
+        is re-read from self._slots each iteration — a snapshot would hand
+        slots whose pages were just freed back to _reserve()."""
         for i in range(self.max_batch):
             s = self._slots[i]
             if s is None:
                 continue
             n_new = min(c, len(s.pending)) if len(s.pending) else 1
             self._reserve(s, n_new)
-        live = [s for s in self._slots if s is not None]
-        if not live:
+        if all(s is None for s in self._slots):
             raise RuntimeError("every live request was preempted — pool "
                                "cannot make progress")
         b = self.max_batch
@@ -326,14 +391,13 @@ class ServeEngine:
             q_start[i] = s.length
             n_valid[i] = n
             bt[i] = self.kv.block_table_row(s.req.uid, self.bt_width)
-        with obs.span("serve." + phase, step=self._step_counter,
-                      live=len(live), chunk=c,
-                      pages_in_use=self.kv.pages_in_use):
-            logits, self.caches = self._step_fn(
-                self.params, jnp.asarray(tokens), self.caches,
-                jnp.asarray(bt), jnp.asarray(q_start), jnp.asarray(n_valid))
-            logits = np.asarray(logits)   # blocks until device done
-            sampled = np.argmax(logits, axis=-1)
+        return tokens, q_start, n_valid, bt
+
+    def _commit(self, logits: np.ndarray, n_valid: np.ndarray):
+        """Sample each slot's token and advance it; -> (finished requests,
+        tokens emitted)."""
+        now = time.perf_counter()
+        sampled = np.argmax(logits, axis=-1)
         finished: List[Request] = []
         emitted = 0
         for i, s in enumerate(self._slots):
@@ -346,40 +410,35 @@ class ServeEngine:
                 if len(s.pending):
                     continue              # mid-prefill: logits unused
                 self.kv.register_prefix(s.req.uid, s.req.prompt)
-            else:
-                pass                      # decode: next_token now in cache
             tok = int(sampled[i])
+            if not s.out:
+                s.req.t_first_token = now
             s.out.append(tok)
             emitted += 1
             done = (tok == self.eos_id
                     or len(s.out) >= s.req.max_new_tokens
                     or s.length >= self.max_len - 1)
             if done:
-                s.req.out_tokens = list(s.out)
-                finished.append(s.req)
-                self.kv.free_seq(s.req.uid)
+                r = s.req
+                r.out_tokens = list(s.out)
+                finished.append(r)
+                self.kv.free_seq(r.uid)
                 self._slots[i] = None
+                obs.observe("serve_queue_wait_seconds",
+                            r.t_admitted - r.t_queued,
+                            help="queued to last admission, per request")
+                obs.observe("serve_prefill_seconds",
+                            r.t_first_token - r.t_admitted,
+                            help="last admission to first token, per "
+                                 "request")
             else:
                 s.next_token = tok
-        wall = time.perf_counter() - t0
-        pages = self.kv.pages_in_use
-        rec = StepTelemetry(
-            step=self._step_counter, phase=phase, live=len(live),
-            queue_depth=len(self._queue), tokens=emitted,
-            preemptions=self._preempted_now, pages_in_use=pages,
-            page_occupancy=self.kv.occupancy,
-            kv_bytes=pages * self.page_size * self._token_bytes,
-            kv_bytes_dense=self.max_batch * self.max_len * self._token_bytes,
-            prefix_hit_tokens=self.kv.stats.prefix_hit_tokens,
-            wall_s=wall, tokens_per_s=emitted / wall if wall > 0 else 0.0)
-        self._emit_step(rec)
-        self._step_counter += 1
-        return finished
+        return finished, emitted
 
     def _emit_step(self, rec: StepTelemetry) -> None:
-        """One StepTelemetry record lands in all three sinks: the in-memory
-        stream, the caller's on_step hook, and the process registry —
-        serving, benches, and an HTTP scrape read the same numbers."""
+        """One StepTelemetry record lands in both sinks: the in-memory
+        stream and the process registry — serving, benches, and an HTTP
+        scrape read the same numbers."""
         self.step_telemetry.append(rec)
         obs.counter_inc("serve_steps_total", phase=rec.phase,
                         help="engine steps by phase")
@@ -397,8 +456,6 @@ class ServeEngine:
         obs.gauge_set("serve_kv_bytes", rec.kv_bytes)
         obs.gauge_set("serve_prefix_hit_tokens", rec.prefix_hit_tokens)
         obs.observe("serve_step_wall_seconds", rec.wall_s, phase=rec.phase)
-        if self.on_step is not None:
-            self.on_step(rec)
 
     @property
     def pending(self) -> int:
@@ -493,7 +550,6 @@ class ServeEngine:
             self.telemetry.append(record)
             obs.counter_inc("serve_waves_total",
                             help="waves run by the deprecated wave engine")
-            obs.gauge_set("serve_wave_tokens_per_s", record.tokens_per_s)
             if self.on_wave is not None:
                 self.on_wave(record)
             results.update(out)

@@ -1,0 +1,171 @@
+"""ServeEngine's spans on the JAX profiler's clock and its per-request
+host-clock stamps (serve/engine.py, obs/trace.py), read back from a CPU
+``jax.profiler`` trace."""
+import glob
+import os
+
+import numpy as np
+import pytest
+
+import jax
+from jax.profiler import ProfileData
+
+from repro import obs
+from repro.obs.trace import Tracer, set_tracer
+
+CHILDREN = ["serve.admit", "serve.schedule", "serve.dispatch", "serve.sync",
+            "serve.commit", "serve.telemetry"]
+
+
+@pytest.fixture(scope="module")
+def engine_setup():
+    from repro.configs import base as cb
+    from repro.models.transformer import build_model
+    cfg = cb.get("phi3-mini-3.8b", smoke=True)
+    model = build_model(cfg, policy="bf16", remat=False)
+    params = model.init(jax.random.PRNGKey(0))
+    return cfg, model, params
+
+
+@pytest.fixture
+def registry():
+    reg = obs.MetricsRegistry()
+    prev = obs.set_registry(reg)
+    try:
+        yield reg
+    finally:
+        obs.set_registry(prev)
+
+
+def _prompt(cfg, n, seed):
+    return np.random.default_rng(seed).integers(
+        2, cfg.vocab, (n,)).astype(np.int32)
+
+
+def _drain(eng, limit=300):
+    done = {}
+    for _ in range(limit):
+        for r in eng.step():
+            done[r.uid] = r
+        if not eng.pending:
+            return done
+    raise AssertionError("engine did not drain")
+
+
+def _host_events(logdir, prefix):
+    """(name, start_ns, end_ns, stats) of the host events named prefix*,
+    in order of start (an enclosing span first)."""
+    path = sorted(glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    pd = ProfileData.from_file(path)
+    events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+               dict(ev.stats))
+              for plane in pd.planes if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith(prefix)]
+    return sorted(events, key=lambda e: (e[1], -e[2]))
+
+
+def _profiled(logdir, fn):
+    """Run fn with an ambient Tracer inside a CPU profiler trace."""
+    tracer = Tracer()
+    prev = set_tracer(tracer)
+    jax.profiler.start_trace(str(logdir))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+        set_tracer(prev)
+    return tracer, out
+
+
+def test_span_and_annotation_reach_the_profiler(tmp_path):
+    def body():
+        with obs.span("serve.outer", layer=3):
+            with obs.span("serve.inner"):
+                obs.annotate(finished=2)
+    tracer, _ = _profiled(tmp_path, body)
+    ev = {n: (a, b, s) for n, a, b, s in _host_events(tmp_path, "serve.")}
+    assert set(ev) == {"serve.outer", "serve.inner"}
+    (oa, ob, os_), (ia, ib, is_) = ev["serve.outer"], ev["serve.inner"]
+    assert oa <= ia <= ib <= ob
+    assert os_ == {"layer": 3} and is_ == {"finished": 2}
+    # The in-memory Chrome export keeps the same spans and args.
+    chrome = {e["name"]: e["args"] for e in tracer.events()}
+    assert chrome == {"serve.outer": {"layer": 3},
+                      "serve.inner": {"finished": 2}}
+
+
+def test_engine_step_span_tree(engine_setup, tmp_path, registry):
+    from repro.serve.engine import ServeEngine
+    cfg, model, params = engine_setup
+    eng = ServeEngine(model, params, max_len=64, max_batch=2, page_size=8,
+                      prefill_chunk=4)
+    for i, n in enumerate((8, 6, 4)):
+        eng.add_request(_prompt(cfg, n, i), max_new_tokens=3, uid=i)
+    _, done = _profiled(tmp_path, lambda: _drain(eng))
+    events = _host_events(tmp_path, "serve.")
+    steps = [e for e in events if e[0] == "serve.step"]
+    tel = eng.step_telemetry
+    assert len(steps) == len(tel) and len(done) == 3
+    widths_seen = set()
+    for (_, a, b, args), rec in zip(steps, tel):
+        assert args == {"step": rec.step, "phase": rec.phase,
+                        "live": rec.live, "chunk": args["chunk"]}
+        assert args["chunk"] == (1 if rec.phase == "decode" else 4)
+        inside = [e for e in events if a <= e[1] and e[2] <= b
+                  and e[0] != "serve.step"]
+        assert [e[0] for e in inside] == CHILDREN
+        kid = {e[0]: e[3] for e in inside}
+        assert kid["serve.admit"]["step"] == rec.step
+        assert kid["serve.schedule"] == {"preempted": rec.preemptions}
+        assert kid["serve.dispatch"] == {
+            "new_shape": int(args["chunk"] not in widths_seen)}
+        widths_seen.add(args["chunk"])
+        # Children run one after another.
+        assert all(x[2] <= y[1] for x, y in zip(inside, inside[1:]))
+    assert sum(e[3]["admitted"] for e in events
+               if e[0] == "serve.admit") == 3
+    assert sum(e[3]["finished"] for e in events
+               if e[0] == "serve.commit") == 3
+    shapes = registry.counter("serve_new_step_shapes_total")
+    assert shapes.value(chunk=4) == 1.0 and shapes.value(chunk=1) == 1.0
+
+
+def test_untraced_step_records_nothing(engine_setup, tmp_path):
+    from repro.serve.engine import ServeEngine
+    cfg, model, params = engine_setup
+    eng = ServeEngine(model, params, max_len=64, max_batch=2, page_size=8)
+    eng.add_request(_prompt(cfg, 6, 0), max_new_tokens=2)
+    assert obs.get_tracer() is None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _drain(eng)
+    finally:
+        jax.profiler.stop_trace()
+    assert _host_events(tmp_path, "serve.") == []
+
+
+def test_request_stamps_and_histograms(engine_setup, registry):
+    from repro.serve.engine import ServeEngine
+    cfg, model, params = engine_setup
+    # A pool too small for both requests' full lengths forces preemption.
+    eng = ServeEngine(model, params, max_len=64, max_batch=2, page_size=8,
+                      max_pages=8)
+    queued = {}
+    for uid in (0, 1):
+        eng.add_request(_prompt(cfg, 10, uid + 4), max_new_tokens=16,
+                        uid=uid)
+        queued[uid] = eng._queue[-1].t_queued
+    done = _drain(eng)
+    preempted = sum(s.preemptions for s in eng.step_telemetry)
+    assert preempted > 0
+    assert sum(r.preemptions for r in done.values()) == preempted
+    for uid, r in done.items():
+        assert r.t_queued == queued[uid]        # kept across preemption
+        assert r.t_queued <= r.t_admitted <= r.t_first_token
+    wait = registry.histogram("serve_queue_wait_seconds").snapshot_one()
+    prefill = registry.histogram("serve_prefill_seconds").snapshot_one()
+    assert wait["count"] == prefill["count"] == 2
+    assert prefill["sum"] == pytest.approx(
+        sum(r.t_first_token - r.t_admitted for r in done.values()))
