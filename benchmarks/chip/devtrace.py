@@ -16,11 +16,7 @@ the busy time (the union of the op intervals), the time the harness
 spent waiting for arrivals (``bench.wait``), the time of each kernel
 class (ops whose name matches a class pattern), the ops that took most
 time (by instruction name), and the longest idle gaps, each labelled by
-the innermost span open at its midpoint.  The engine's own spans
-(``serve.step`` and its children, from ``repro.obs``) share that host
-plane: for every span name, how many spans lie wholly inside the traced
-span, their host time and the device idle time inside them; and the
-device idle time of the traced span split by the innermost span open.
+the innermost harness span open at its midpoint.
 """
 from __future__ import annotations
 
@@ -31,13 +27,9 @@ import re
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
-import numpy as np
-
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 SPAN_PREFIX = "bench."
-SERVE_PREFIX = "serve."
-OUTSIDE = "outside harness spans"
 TRACED_SPAN = "bench.traced"
 WAIT_SPAN = "bench.wait"
 TOP = 10
@@ -54,15 +46,6 @@ class Reduced:
     kernel_s: Dict[str, float]          # class -> summed op time, mean over chips
     device_ops: List[Tuple[str, float]]  # top ops by summed time
     idle_gaps: List[Tuple[str, float]]   # longest gaps, by host span
-    spans: Dict[str, "SpanTime"]         # span name -> count and times
-    idle_by_label: Dict[str, float]     # innermost span -> idle, mean over chips
-
-
-@dataclasses.dataclass
-class SpanTime:
-    count: int            # spans of one name wholly inside the traced span
-    total_s: float        # their host time
-    idle_s: float         # device idle inside them, mean over chips
 
 
 def find_xplane(logdir: str) -> str:
@@ -80,7 +63,7 @@ def _host_spans(pd) -> List[Tuple[float, float, str]]:
             continue
         for line in plane.lines:
             for ev in line.events:
-                if ev.name.startswith((SPAN_PREFIX, SERVE_PREFIX)):
+                if ev.name.startswith(SPAN_PREFIX):
                     spans.append((ev.start_ns, ev.start_ns + ev.duration_ns,
                                   ev.name))
     return spans
@@ -103,41 +86,7 @@ def _label(spans, t: float) -> str:
         if a <= t <= b and name != TRACED_SPAN and (
                 best is None or b - a < best[1] - best[0]):
             best = (a, b, name)
-    return best[2] if best else OUTSIDE
-
-
-def _idle_before(idle: List[Tuple[float, float]], ts) -> np.ndarray:
-    """Idle time from the first idle interval up to each time in ``ts``;
-    ``idle`` is sorted and disjoint."""
-    ts = np.asarray(ts, np.float64)
-    if not idle:
-        return np.zeros_like(ts)
-    a = np.array([x for x, _ in idle])
-    d = np.array([y - x for x, y in idle])
-    cum = np.concatenate([[0.0], np.cumsum(d)])
-    j = np.searchsorted(a, ts, side="right")
-    k = np.maximum(j - 1, 0)
-    part = np.where(j > 0, np.clip(ts - a[k], 0.0, d[k]), 0.0)
-    return cum[k] + part
-
-
-def _segments(spans, lo: float, hi: float) -> Tuple[List[float], List[str]]:
-    """Cut [lo, hi] at every span edge: (edges, innermost span name of
-    each piece between two edges)."""
-    inner = [(max(a, lo), min(b, hi), b - a, n) for a, b, n in spans
-             if n != TRACED_SPAN and min(b, hi) > max(a, lo)]
-    edges = sorted({lo, hi, *(a for a, _, _, _ in inner),
-                    *(b for _, b, _, _ in inner)})
-    by_start = sorted(inner)
-    names, active, i = [], [], 0
-    for p in edges[:-1]:
-        while i < len(by_start) and by_start[i][0] <= p:
-            active.append(by_start[i])
-            i += 1
-        active = [s for s in active if s[1] > p]
-        names.append(min(active, key=lambda s: s[2])[3] if active
-                     else OUTSIDE)
-    return edges, names
+    return best[2] if best else "outside harness spans"
 
 
 def reduce(pd, classes: Dict[str, str]) -> Optional[Reduced]:
@@ -155,11 +104,6 @@ def reduce(pd, classes: Dict[str, str]) -> Optional[Reduced]:
     pats = {k: re.compile(v) for k, v in classes.items()}
     busy = 0.0
     chips = 0
-    whole = [(a, b, n) for a, b, n in spans
-             if n != TRACED_SPAN and a >= lo and b <= hi]
-    edges, names = _segments(spans, lo, hi)
-    span_idle = np.zeros(len(whole))
-    label_idle = defaultdict(float)
     kernel = defaultdict(float)
     ops = defaultdict(float)
     gaps: List[Tuple[float, float]] = []
@@ -189,36 +133,21 @@ def reduce(pd, classes: Dict[str, str]) -> Optional[Reduced]:
         merged = _union(ivals)
         busy += sum(b - a for a, b in merged)
         prev = lo
-        idle = []
         for a, b in merged + [(hi, hi)]:
             if a > prev:
                 gaps.append((a - prev, (a + prev) / 2))
-                idle.append((prev, a))
             prev = max(prev, b)
-        if whole:
-            span_idle += (_idle_before(idle, [b for _, b, _ in whole])
-                          - _idle_before(idle, [a for a, _, _ in whole]))
-        for name, t in zip(names, np.diff(_idle_before(idle, edges))):
-            label_idle[name] += float(t)
     if not chips:
         return None
     gaps = [(_label(spans, mid), d / 1e9)
             for d, mid in sorted(gaps, reverse=True)[:TOP]]
     top = sorted(ops.items(), key=lambda kv: -kv[1])[:TOP]
-    by_name: Dict[str, SpanTime] = {}
-    for (a, b, name), t in zip(whole, span_idle):
-        st = by_name.setdefault(name, SpanTime(0, 0.0, 0.0))
-        st.count += 1
-        st.total_s += (b - a) / 1e9
-        st.idle_s += float(t) / chips / 1e9
     return Reduced(
         window_s=(hi - lo) / 1e9, busy_s=busy / chips / 1e9,
         waiting_s=waiting / 1e9, chips=chips,
         kernel_s={k: kernel.get(k, 0.0) / chips / 1e9 for k in classes},
         device_ops=[(n, t / chips / 1e9) for n, t in top],
-        idle_gaps=gaps, spans=by_name,
-        idle_by_label={k: t / chips / 1e9 for k, t in label_idle.items()
-                       if t > 0})
+        idle_gaps=gaps)
 
 
 def reduce_file(path: str, classes: Dict[str, str]) -> Optional[Reduced]:

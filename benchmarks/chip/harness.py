@@ -16,8 +16,7 @@ peak device memory, free the program's state, and compare the served
 tokens of a seeded sample of finished requests with the float32
 reference (``reference.py``).  With tracing on, the profiler records the
 first ``TRACE_SECONDS`` of the window and the per-layer metrics are read
-from that span; an ``obs.Tracer`` is installed for that span alone, so the
-engine's own ``serve.*`` spans land in the same trace.
+from that span.
 """
 from __future__ import annotations
 
@@ -216,8 +215,6 @@ class Driver:
             rec = self.served[req.uid]
             rec.finish_step = idx
             rec.out = list(req.out_tokens)
-            for stamp in timeline.STAMPS:
-                setattr(rec, stamp, getattr(req, stamp, None))
         if tel.preemptions:
             raise timeline.TimelineError(
                 f"step {idx} preempted {tel.preemptions} requests; the "
@@ -225,14 +222,12 @@ class Driver:
 
     def run(self, seconds: float, trace_dir: Optional[str]) -> Window:
         import jax
-        from repro import obs
         ann = jax.profiler.TraceAnnotation
         late: List[float] = []
         tracing = trace_dir is not None
         if tracing:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
-            prev_tracer = obs.set_tracer(obs.Tracer())
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
             traced = ann("bench.traced")
             traced.__enter__()
@@ -255,13 +250,11 @@ class Driver:
             if tracing and time.perf_counter() >= trace_end:
                 traced.__exit__(None, None, None)
                 jax.profiler.stop_trace()
-                obs.set_tracer(prev_tracer)
                 traced_steps = range(0, len(self.steps))
                 tracing = False
         if tracing:
             traced.__exit__(None, None, None)
             jax.profiler.stop_trace()
-            obs.set_tracer(prev_tracer)
             traced_steps = range(0, len(self.steps))
         n = len(self.steps)
         end = max(deadline, self.steps[-1].t_end if n else deadline)
@@ -585,12 +578,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         result["breakdown"] = {
             "device_ops": [[n, t] for n, t in reduced.device_ops],
             "idle_gaps": [[n, t] for n, t in reduced.idle_gaps]}
-        for name, sp in sorted(reduced.spans.items()):
-            say(f"span {name}: {sp.count} x {sp.total_s / sp.count * 1e3:.3f}"
-                f" ms, device idle {sp.idle_s / sp.count * 1e3:.3f} ms each")
-        for name, t in sorted(reduced.idle_by_label.items(),
-                              key=lambda kv: -kv[1]):
-            say(f"idle in {name}: {t * 1e3:.3f} ms")
     if win.late_s:
         say(f"generator lateness: max {max(win.late_s) * 1e3:.3f} ms over "
             f"{len(win.late_s)} idle waits")

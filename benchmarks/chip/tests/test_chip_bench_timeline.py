@@ -102,52 +102,3 @@ def test_preemption_and_inconsistent_counts_raise():
         timeline.implied_counts(steps, served, 64)
     served[0] = dataclasses.replace(served[0], out=[5])
     assert timeline.explained(steps, served, 64, 0, 1)
-
-
-def _serve_poisson(program, cell, seconds, trace_dir=None, requests=48):
-    mix = dict(cell.mix, requests=requests,
-               arrival={"kind": "poisson", "rate_per_s": 40.0})
-    drv = harness.Driver(program.eng, Traffic(mix, program.arch.vocab, 17),
-                         cell)
-    win = drv.run(seconds, trace_dir)
-    while program.eng.pending:          # finish what was queued, add nothing
-        drv.step()
-    return drv, win
-
-
-def test_engine_stamps_agree_with_the_derived_timeline():
-    """The engine's own stamps of every finished request bracket the step
-    that the timeline derives as its first-token step."""
-    cell, program = _program("granite-moe.short-poisson")
-    drv, _ = _serve_poisson(program, cell, 1.0)
-    done = [r for r in drv.served.values() if r.done]
-    assert len(done) >= 4
-    for r in done:
-        first = drv.steps[r.first_step()]
-        assert (r.due <= r.t_queued <= r.t_admitted <= r.t_first_token
-                <= first.t_end)
-        # The stamp falls inside that step, and inside no other.
-        engine_first = [i for i, s in enumerate(drv.steps)
-                        if s.t_start <= r.t_first_token <= s.t_end]
-        assert engine_first == [r.first_step()]
-        assert drv.steps[r.prefill_start(drv.chunk)].t_start <= r.t_admitted
-
-
-def test_traced_span_holds_the_engine_spans(tmp_path, monkeypatch):
-    """The harness installs a tracer for the traced span alone: every
-    traced step's ``serve.step`` lies inside ``bench.traced``, and no
-    engine span is recorded once the span ends."""
-    from jax.profiler import ProfileData
-    from benchmarks.chip import devtrace
-    from repro import obs
-    cell, program = _program("granite-moe.short-poisson")
-    monkeypatch.setattr(harness, "TRACE_SECONDS", 0.5)
-    drv, win = _serve_poisson(program, cell, 1.5, str(tmp_path))
-    assert obs.get_tracer() is None
-    spans = devtrace._host_spans(ProfileData.from_file(
-        devtrace.find_xplane(str(tmp_path))))
-    (lo, hi), = [(a, b) for a, b, n in spans if n == devtrace.TRACED_SPAN]
-    steps = [(a, b) for a, b, n in spans if n == "serve.step"]
-    assert len(steps) == len(win.traced) > 0
-    assert all(lo <= a and b <= hi for a, b in steps)
-    assert len(drv.steps) > len(win.traced)

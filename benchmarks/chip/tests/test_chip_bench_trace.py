@@ -98,117 +98,3 @@ def test_reduction_of_a_recorded_chip_trace():
     assert len(r.idle_gaps) == devtrace.TOP
     assert all(g[0].startswith("bench.") or g[0] == "outside harness spans"
                for g in r.idle_gaps)
-
-
-# The engine's spans inside the harness's (ns): one step wholly inside the
-# traced span [1000, 21000], a second that outlasts it.  Device ops
-# [3500, 7000] and [11000, 20000]; idle [1000, 3500], [7000, 11000] and
-# [20000, 21000].
-SERVE = [("bench.traced", 1000, 21000), ("bench.engine_step", 2000, 10000),
-         ("serve.step", 2200, 9800), ("serve.admit", 2200, 2600),
-         ("serve.schedule", 2600, 3000), ("serve.dispatch", 3000, 4000),
-         ("serve.sync", 4000, 8000), ("serve.commit", 8000, 9400),
-         ("serve.telemetry", 9400, 9800), ("bench.traffic", 10000, 10500),
-         ("bench.engine_step", 10500, 22000), ("serve.step", 10600, 21900)]
-
-
-def _serve_trace():
-    names = sorted({n for n, _, _ in SERVE})
-    ids = {n: i + 1 for i, n in enumerate(names)}
-    host = "".join(
-        f"    events {{ metadata_id: {ids[n]} offset_ps: {a * 1000} "
-        f"duration_ps: {(b - a) * 1000} }}\n" for n, a, b in SERVE)
-    meta = "".join(f'  event_metadata {{ key: {i} value {{ id: {i} '
-                   f'name: "{n}" }} }}\n' for n, i in ids.items())
-    txt = f'''
-planes {{ id: 1 name: "/device:TPU:0"
-  lines {{ id: 1 name: "XLA Ops" timestamp_ns: 0
-    events {{ metadata_id: 1 offset_ps: 3500000 duration_ps: 3500000 }}
-    events {{ metadata_id: 1 offset_ps: 11000000 duration_ps: 9000000 }} }}
-  event_metadata {{ key: 1 value {{ id: 1 name: "fusion.1" }} }} }}
-planes {{ id: 2 name: "/host:CPU"
-  lines {{ id: 1 name: "python" timestamp_ns: 0
-{host}  }}
-{meta}}}
-'''
-    return ProfileData.from_serialized_xspace(
-        ProfileData.text_proto_to_serialized_xspace(txt))
-
-
-def test_serve_spans_label_the_idle_time():
-    r = devtrace.reduce(_serve_trace(), {})
-    assert r.busy_s == pytest.approx(12.5e-6)
-    assert r.idle_gaps == [("serve.commit", pytest.approx(4e-6)),
-                           ("serve.admit", pytest.approx(2.5e-6)),
-                           ("serve.step", pytest.approx(1e-6))]
-    # Idle split by the innermost span open; it sums to all idle time.
-    want = {devtrace.OUTSIDE: 1000, "bench.engine_step": 500,
-            "serve.admit": 400, "serve.schedule": 400,
-            "serve.dispatch": 500, "serve.sync": 1000, "serve.commit": 1400,
-            "serve.telemetry": 400, "bench.traffic": 500, "serve.step": 1400}
-    assert r.idle_by_label == {k: pytest.approx(v * 1e-9)
-                               for k, v in want.items()}
-    assert sum(r.idle_by_label.values()) == pytest.approx(
-        r.window_s - r.busy_s)
-
-
-def test_serve_spans_wholly_inside_the_traced_span():
-    r = devtrace.reduce(_serve_trace(), {})
-    # Only the first step and the first bench.engine_step end inside it.
-    step = r.spans["serve.step"]
-    assert (step.count, step.total_s) == (1, pytest.approx(7.6e-6))
-    assert step.idle_s == pytest.approx(4.1e-6)    # [2200, 3500] + [7000, 9800]
-    assert r.spans["bench.engine_step"].idle_s == pytest.approx(4.5e-6)
-    assert r.spans["serve.sync"].idle_s == pytest.approx(1e-6)
-    assert r.spans["serve.dispatch"].idle_s == pytest.approx(0.5e-6)
-    assert "bench.traced" not in r.spans
-
-
-# What the reduction read on the recorded trace before it learnt of the
-# engine's spans: adding them changes none of these.
-RECORDED_BEFORE = {
-    "window_s": 0.288589079, "busy_s": 0.273831616, "waiting_s": 0.0,
-    "kernel_s": {"mpgemm": 0.021850186, "paged_attention": 0.169830416}}
-
-
-@pytest.mark.parametrize("field", sorted(RECORDED_BEFORE))
-def test_recorded_chip_trace_reads_as_before(field):
-    import gzip
-    from benchmarks.chip import harness
-    with gzip.open(RECORDED, "rb") as f:
-        pd = ProfileData.from_serialized_xspace(f.read())
-    classes = {}
-    for name in ("mpgemm_roofline", "paged_attention_roofline"):
-        classes.update(harness.load_reader(name).KERNELS)
-    r = devtrace.reduce(pd, classes)
-    want = RECORDED_BEFORE[field]
-    got = getattr(r, field)
-    if isinstance(want, dict):
-        assert got == {k: pytest.approx(v, abs=1e-12) for k, v in want.items()}
-    else:
-        assert got == pytest.approx(want, abs=1e-12)
-
-
-RECORDED_SPANS = os.path.join(
-    os.path.dirname(__file__), "data",
-    "granite-moe.short-poisson.serve-spans.xplane.pb.gz")
-
-
-def test_recorded_engine_spans_label_the_idle_time():
-    """Three engine steps of granite-moe.short-poisson traced by the
-    harness on a TPU v5e with the engine's ``serve.*`` spans (cut from a
-    10 s trace; host threads other than the harness's and op stats
-    removed): the idle time inside ``bench.engine_step`` lies in the
-    children of ``serve.step``."""
-    import gzip
-    with gzip.open(RECORDED_SPANS, "rb") as f:
-        pd = ProfileData.from_serialized_xspace(f.read())
-    r = devtrace.reduce(pd, {})
-    assert r.spans["serve.step"].count == 3
-    assert all(g[0].startswith("serve.") for g in r.idle_gaps)
-    step_idle = r.spans["bench.engine_step"].idle_s
-    own = r.idle_by_label.get("serve.step", 0.0) + r.idle_by_label.get(
-        "bench.engine_step", 0.0)
-    assert step_idle > 0 and own / step_idle < 0.05
-    assert r.spans["serve.sync"].idle_s > 0
-    assert r.spans["serve.dispatch"].idle_s > 0

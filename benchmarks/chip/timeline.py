@@ -25,11 +25,6 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 
-# The engine's host-clock stamps of a request (``repro.serve.engine.Request``),
-# copied into ``Served`` when it finishes; None where the engine has none.
-STAMPS = ("t_queued", "t_admitted", "t_first_token")
-
-
 class TimelineError(RuntimeError):
     """The engine did not follow the schedule the derivation assumes."""
 
@@ -56,9 +51,6 @@ class Served:
     added_step: int        # index of the first step after it was queued
     finish_step: int = -1
     out: Optional[List[int]] = None
-    t_queued: Optional[float] = None       # engine stamps (STAMPS)
-    t_admitted: Optional[float] = None
-    t_first_token: Optional[float] = None
 
     @property
     def done(self) -> bool:
@@ -143,13 +135,6 @@ def step_rows(served: Dict[int, Served], chunk: int, lo: int,
             if lo <= first + i < hi:
                 rows[first + i - lo].append((1, r.prompt_len + i - 1))
     return rows
-
-
-def first_token_by(served: Dict[int, Served], end: float) -> List[Served]:
-    """Requests whose engine stamps say their first token came by ``end``
-    (host clock); empty where the engine sets no stamps."""
-    return [r for r in served.values()
-            if r.t_first_token is not None and r.t_first_token <= end]
 
 
 def weighted_percentile(values: np.ndarray, counts: np.ndarray,
