@@ -1,5 +1,5 @@
 """Continuous-batching serve: modeled paged-vs-dense KV bytes, the
-page-visit gate, and the end-to-end engine smoke.
+page-walk gate, and the end-to-end engine smoke.
 
 Three measurement families (area ``serve``, -> ``BENCH_serve.json``):
 
@@ -9,14 +9,13 @@ Three measurement families (area ``serve``, -> ``BENCH_serve.json``):
                          actual lengths; the paged store holds
                          ``ceil(len/ps)*ps`` per request.  Deterministic —
                          the paged-vs-dense memory story the redesign ships.
-  * ``serve_trace_*``  — the **page-visit gate**: the traced jaxpr of the
+  * ``serve_trace_*``  — the **page-walk gate**: the traced jaxpr of the
                          paged flash-attention launch has grid
-                         ``(B, Hkv, G, nq, W)`` with W the block-table
-                         width, so the number of KV pages each query block
-                         walks is a trace-time fact — ``--smoke`` asserts
-                         it equals the table width and SHRINKS with
-                         narrower tables (exactly the stored-tile schedule
-                         argument bench_sparse.py makes for MPGEMM).
+                         ``(B, head groups, q blocks)`` at every block-table
+                         width — the KV walk is a loop inside each program,
+                         bounded by each row's length, so the table width
+                         no longer multiplies the grid.  ``--smoke``
+                         asserts that shape at widths 8, 4 and 2.
   * ``serve_e2e_*``    — a real continuous-batching run (smoke model):
                          short requests must retire strictly before a long
                          co-scheduled one (no head-of-line stall), the
@@ -93,15 +92,16 @@ def run(rows=None):
     return rows
 
 
-def _traced_page_visits(b, hkv, g, tq, d, ps, width) -> tuple:
+def _traced_grid(b, hkv, g, tq, d, ps, width) -> tuple:
     """The pallas grid of a paged flash-attention launch (trace only)."""
-    from repro.kernels.flash_attention import paged_flash_attention
+    from repro.kernels.flash_attention import (kv_page_shape,
+                                               paged_flash_attention)
 
-    n_pages = 1 + b * width
+    pages = kv_page_shape(1 + b * width, hkv, ps, d)
     args = (
         jax.ShapeDtypeStruct((b, hkv * g, tq, d), jnp.float32),
-        jax.ShapeDtypeStruct((n_pages, hkv, ps, d), jnp.float32),
-        jax.ShapeDtypeStruct((n_pages, hkv, ps, d), jnp.float32),
+        jax.ShapeDtypeStruct(pages, jnp.float32),
+        jax.ShapeDtypeStruct(pages, jnp.float32),
         jax.ShapeDtypeStruct((b, width), jnp.int32),
         jax.ShapeDtypeStruct((b,), jnp.int32),
         jax.ShapeDtypeStruct((b,), jnp.int32),
@@ -111,30 +111,30 @@ def _traced_page_visits(b, hkv, g, tq, d, ps, width) -> tuple:
 
 
 def run_trace_gate(assert_gate: bool = False):
-    """The jaxpr proof that the kernel walks the BLOCK TABLE, not the pool:
-    the innermost grid axis is the table width, so shrinking the table
-    shrinks the traced KV walk while the page pool stays put."""
+    """The jaxpr proof that the block table no longer shapes the launch:
+    the grid is (B, head groups, query blocks) at every table width; the
+    page walk runs inside each program, as far as each row's length."""
+    from repro.kernels.flash_attention import kv_page_shape
+
     b, hkv, g, tq, d, ps = 2, 2, 2, 8, 64, 8
-    visits = {}
+    groups = kv_page_shape(1, hkv, ps, d)[1]
+    want = (b, groups, 1)       # hb*G*Tq = 32 query rows fit one block
+    grids = {}
     for width in (8, 4, 2):
-        grid = _traced_page_visits(b, hkv, g, tq, d, ps, width)
-        visits[width] = grid[-1]
+        grid = _traced_grid(b, hkv, g, tq, d, ps, width)
+        grids[width] = grid
         emit(f"serve_trace_w{width}", 0.0,
-             f"grid={grid};page_visits={grid[-1]};table_width={width}")
+             f"grid={grid};grid_steps={int(np.prod(grid))};"
+             f"table_width={width}")
         record(f"serve_trace_w{width}", "serve", kind="trace",
                workload={"b": b, "hkv": hkv, "g": g, "tq": tq, "d": d,
                          "page_size": ps, "table_width": width},
-               metrics={"page_visits": float(grid[-1]),
-                        "grid_steps": float(int(np.prod(grid)))})
+               metrics={"grid_steps": float(int(np.prod(grid)))})
         if assert_gate:
-            assert grid[-1] == width, (
-                f"traced grid walks {grid[-1]} pages per query block, "
-                f"block table has {width} — the launch is not steered by "
-                f"the scalar-prefetched table")
-    if assert_gate:
-        assert visits[8] > visits[4] > visits[2], (
-            f"page visits {visits} not shrinking with the block table")
-    return visits
+            assert tuple(grid) == want, (
+                f"traced grid {grid} at table width {width}, want {want}: "
+                f"the launch is not (B, head groups, q blocks)")
+    return grids
 
 
 def run_e2e(assert_gate: bool = False):
@@ -202,15 +202,15 @@ def run_e2e(assert_gate: bool = False):
                 stall_gap=stall_gap)
 
 
-def write_report(rows, visits, e2e, out_dir: str) -> str:
+def write_report(rows, grids, e2e, out_dir: str) -> str:
     os.makedirs(out_dir, exist_ok=True)
     path = os.path.join(out_dir, "serve_report.md")
     lines = [
         "# Continuous-batching serve: paged KV, end to end",
         "",
         "Modeled terms are pure KV accounting (dense wave allocation vs "
-        "page-rounded actual lengths); page visits are trace-time facts "
-        "from the paged flash-attention grid; e2e numbers are one smoke "
+        "page-rounded actual lengths); grids are trace-time facts "
+        "of the paged flash-attention launch; e2e numbers are one smoke "
         "run of the continuous engine on CPU.",
         "",
         "| arch | mix | paged KV | dense KV | saving |",
@@ -220,10 +220,9 @@ def write_report(rows, visits, e2e, out_dir: str) -> str:
         lines.append(
             f"| {r['arch']} | {r['mix']} | {r['kv_bytes_paged']:,} "
             f"| {r['kv_bytes_dense']:,} | {r['saving']:.0%} |")
-    lines += ["", "## Page-visit gate (traced grid)", ""]
-    for width, v in visits.items():
-        lines.append(f"- table width {width}: {v} page visits per "
-                     f"query block")
+    lines += ["", "## Page-walk gate (traced grid)", ""]
+    for width, grid in grids.items():
+        lines.append(f"- table width {width}: grid {grid}")
     lines += [
         "",
         "## Engine smoke",
@@ -243,18 +242,19 @@ def write_report(rows, visits, e2e, out_dir: str) -> str:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="hard gates: page visits == table width and "
-                         "shrink with it, no head-of-line stall, paged < "
-                         "dense KV every step (CI gate)")
+                    help="hard gates: the paged-attention grid is (B, "
+                         "head groups, q blocks) at every table width, no "
+                         "head-of-line stall, paged < dense KV every step "
+                         "(CI gate)")
     args = ap.parse_args()
 
     rows = run()
-    visits = run_trace_gate(assert_gate=args.smoke)
+    grids = run_trace_gate(assert_gate=args.smoke)
     e2e = run_e2e(assert_gate=args.smoke)
 
     out_dir = os.environ.get("REPRO_SERVE_OUT")
     if out_dir:
-        print(f"report: {write_report(rows, visits, e2e, out_dir)}")
+        print(f"report: {write_report(rows, grids, e2e, out_dir)}")
 
 
 if __name__ == "__main__":

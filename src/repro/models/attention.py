@@ -419,7 +419,8 @@ def attention_core(
 # --------------------------------------------------------------------------
 # Paged attention (continuous-batching serving; docs/serving.md)
 #
-# KV lives in a pooled page array (P, Hkv, page_size, D); each request's
+# KV lives in a pooled page array (kernels.flash_attention.kv_page_shape:
+# (P, Hkv/hb, page_size, lanes), hb heads per row); each request's
 # logical KV stream is the concatenation of the pages its block-table row
 # names.  Positions in the math below are LOGICAL (page j, offset o ->
 # j*page_size + o); which physical page backs them is irrelevant to masking.
@@ -432,13 +433,14 @@ def paged_kv_write(k_pages, v_pages, k_new, v_new, block_tables, q_start,
 
     ``k_new``/``v_new``: (B, Hkv, C, D); token i of request b lands at
     logical position ``q_start[b] + i`` -> physical page
-    ``block_tables[b, pos // ps]``, offset ``pos % ps``.  Rows with
+    ``block_tables[b, pos // ps]``, offset ``pos % ps``, its heads side by
+    side as the pool's rows hold them (``kv_page_shape``).  Rows with
     ``i >= n_valid[b]`` are dead: they are routed to the reserved scratch
     page 0 (slot ``(b*C + i) % ps`` — scratch content is never read as
     valid, the attention mask kills it).
     """
     b, hkv, c, d = k_new.shape
-    ps = k_pages.shape[2]
+    groups, ps = k_pages.shape[1:3]
     w = block_tables.shape[1]
     pos = q_start[:, None] + jnp.arange(c)[None, :]            # (B, C)
     page = jnp.take_along_axis(
@@ -450,10 +452,11 @@ def paged_kv_write(k_pages, v_pages, k_new, v_new, block_tables, q_start,
     offset = jnp.where(valid, offset, scratch_off)
     pg = page.reshape(-1)
     off = offset.reshape(-1)
-    k_rows = k_new.transpose(0, 2, 1, 3).reshape(b * c, hkv, d)
-    v_rows = v_new.transpose(0, 2, 1, 3).reshape(b * c, hkv, d)
-    k_pages = k_pages.at[pg, :, off].set(k_rows.astype(k_pages.dtype))
-    v_pages = v_pages.at[pg, :, off].set(v_rows.astype(v_pages.dtype))
+    width = hkv // groups * d                      # a row's heads, unpadded
+    k_rows = k_new.transpose(0, 2, 1, 3).reshape(b * c, groups, width)
+    v_rows = v_new.transpose(0, 2, 1, 3).reshape(b * c, groups, width)
+    k_pages = k_pages.at[pg, :, off, :width].set(k_rows.astype(k_pages.dtype))
+    v_pages = v_pages.at[pg, :, off, :width].set(v_rows.astype(v_pages.dtype))
     return k_pages, v_pages
 
 
@@ -463,15 +466,19 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, q_start, lengths,
     per-request KV stream, then masked grouped-GQA softmax.  Numerically
     the oracle for the Pallas kernel and the CPU serving path."""
     b, h, tq, d = q.shape
-    p_pages, hkv, ps, _ = k_pages.shape
+    p_pages, groups, ps, lanes = k_pages.shape
+    width = lanes // d * d                         # a row's heads, unpadded
+    hkv = groups * width // d
     w = block_tables.shape[1]
     g = h // hkv
     s_max = w * ps
     scale = scale if scale is not None else 1.0 / d ** 0.5
     tok = (block_tables[:, :, None] * ps
            + jnp.arange(ps)[None, None, :]).reshape(b, s_max)   # (B, S)
-    kf = k_pages.transpose(0, 2, 1, 3).reshape(p_pages * ps, hkv, d)
-    vf = v_pages.transpose(0, 2, 1, 3).reshape(p_pages * ps, hkv, d)
+    kf = k_pages[..., :width].transpose(0, 2, 1, 3).reshape(
+        p_pages * ps, hkv, d)
+    vf = v_pages[..., :width].transpose(0, 2, 1, 3).reshape(
+        p_pages * ps, hkv, d)
     k = kf[tok].transpose(0, 2, 1, 3)                           # (B, Hkv, S, D)
     v = vf[tok].transpose(0, 2, 1, 3)
     qg = q.reshape(b, hkv, g, tq, d)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.core import config as gemm_cfg
 from repro.core.gemm import mp_dot, mp_dot_grouped
 from repro.distributed import act
+from repro.kernels.flash_attention import kv_page_shape
 from repro.models import attention as attn
 from repro.models.layers import (
     apply_rope, dense_init, gelu_mlp, init_gelu_mlp, init_swiglu, layernorm,
@@ -172,8 +173,9 @@ def attn_decode(params, x, cache, ctx):
 
 def attn_paged_init_cache(cfg, num_pages, page_size, dtype=jnp.bfloat16):
     """Pooled KV pages shared by every request (serve/kv_cache.py owns the
-    allocation of the leading page axis; page 0 is reserved scratch)."""
-    shape = (num_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
+    allocation of the leading page axis; page 0 is reserved scratch), in
+    the paged kernel's layout: KV heads side by side in each page row."""
+    shape = kv_page_shape(num_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
     return {"k_pages": jnp.zeros(shape, dtype),
             "v_pages": jnp.zeros(shape, dtype)}
 

@@ -401,9 +401,10 @@ class LM:
         return None
 
     def init_paged_caches(self, num_pages: int, page_size: int):
-        """Pooled KV pages, one (num_pages, Hkv, page_size, hd) pair per
-        layer; block tables are shared across layers so the layer axis
-        lives here, exactly like init_caches stacks ring caches."""
+        """Pooled KV pages, one ``kv_page_shape(num_pages, Hkv, page_size,
+        hd)`` pair per layer; block tables are shared across layers so the
+        layer axis lives here, exactly like init_caches stacks ring
+        caches."""
         reason = self.paged_unsupported_reason()
         if reason:
             raise ValueError(reason)

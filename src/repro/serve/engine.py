@@ -25,7 +25,10 @@ existing callers/tests).
 With a tracer installed (``repro.obs``), each continuous step is one
 ``serve.step`` span over ``serve.admit``, ``serve.schedule`` (page
 reservation, host batch arrays), ``serve.dispatch`` (uploads and the
-jitted call, until it returns), ``serve.sync`` (waiting for the chip and
+jitted call, until it returns; ``pages_walked``: the live KV pages the
+attention kernel walks per layer, beside the
+``serve_attention_pages_walked_total`` / ``_table_slots_total``
+counters), ``serve.sync`` (waiting for the chip and
 copying the logits back), ``serve.commit`` (sampling, per-slot updates)
 and ``serve.telemetry``.  Each :class:`Request` carries host-clock stamps
 (``time.perf_counter()``) of its queueing, admission and first token.
@@ -322,12 +325,13 @@ class ServeEngine:
                                             for s in live)
                  else "mixed" if chunk else "decode")
         with obs.span("serve.schedule"):
-            tokens, q_start, n_valid, bt = self._schedule(c)
+            tokens, q_start, n_valid, bt, walked = self._schedule(c)
             obs.annotate(preempted=self._preempted_now)
         live = [s for s in self._slots if s is not None]
         obs.annotate(phase=phase, live=len(live), chunk=c)
         new_shape = c not in self._chunks_dispatched
-        with obs.span("serve.dispatch", new_shape=new_shape):
+        with obs.span("serve.dispatch", new_shape=new_shape,
+                      pages_walked=walked):
             if new_shape:
                 self._chunks_dispatched.add(c)
                 obs.counter_inc("serve_new_step_shapes_total", chunk=c,
@@ -362,8 +366,9 @@ class ServeEngine:
     def _schedule(self, c: int):
         """Reserve pages for this step's writes (may preempt) and build the
         host batch: (tokens (B, c), q_start (B,), n_valid (B,), block
-        tables (B, W)).  _reserve() can evict any NEWER slot, so liveness
-        is re-read from self._slots each iteration — a snapshot would hand
+        tables (B, W), the live pages the attention kernel walks in each
+        layer).  _reserve() can evict any NEWER slot, so liveness is
+        re-read from self._slots each iteration — a snapshot would hand
         slots whose pages were just freed back to _reserve()."""
         for i in range(self.max_batch):
             s = self._slots[i]
@@ -391,7 +396,15 @@ class ServeEngine:
             q_start[i] = s.length
             n_valid[i] = n
             bt[i] = self.kv.block_table_row(s.req.uid, self.bt_width)
-        return tokens, q_start, n_valid, bt
+        walked = int(cdiv(q_start + n_valid, self.page_size).sum())
+        obs.counter_inc("serve_attention_pages_walked_total", amount=walked,
+                        help="live KV pages the paged attention kernel "
+                             "walks, per layer, summed over steps")
+        obs.counter_inc("serve_attention_table_slots_total",
+                        amount=b * self.bt_width,
+                        help="block-table slots of the steps, per layer "
+                             "(max_batch x table width)")
+        return tokens, q_start, n_valid, bt, walked
 
     def _commit(self, logits: np.ndarray, n_valid: np.ndarray):
         """Sample each slot's token and advance it; -> (finished requests,

@@ -1,8 +1,10 @@
 """Paged KV-cache bookkeeping for the continuous-batching ServeEngine.
 
-The device-side KV pool (one ``(num_pages, n_kv_heads, page_size, head_dim)``
-array pair per layer, built by ``LM.init_paged_caches``) is dumb storage;
-this module owns every allocation decision on the host:
+The device-side KV pool (one ``(num_pages, n_kv_heads // hb, page_size,
+hb * head_dim)`` array pair per layer, ``hb`` heads side by side in a row
+(``kernels.flash_attention.kv_page_shape``), built by
+``LM.init_paged_caches``) is dumb storage; this module owns every
+allocation decision on the host:
 
   * **free-list allocation** — pages are handed out LIFO from a free list;
     page 0 is permanently reserved as the *scratch* page, so a block-table

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.blocking import plan_gemm, plan_with_blocks
-from repro.kernels.flash_attention import paged_flash_attention
+from repro.kernels.flash_attention import kv_page_shape, paged_flash_attention
 from repro.kernels.mpgemm import mpgemm_grouped_pallas, mpgemm_pallas
 from repro.packing.pack import pack_operand
 
@@ -104,17 +104,22 @@ def test_packed_payload(one_chip, codec):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("tq", [1, 64], ids=["decode", "prefill"])
-def test_paged_attention(one_chip, tq):
-    """granite's 16 query / 8 KV heads of width 64 over 16-token pages,
-    max_len 512 at batch 4."""
-    b, h, hkv, d, ps, w = 4, 16, 8, 64, 16, 32
-    pages = b * w + 1
+@pytest.mark.parametrize("h,hkv,d,tq", [
+    (16, 8, 64, 1), (16, 8, 64, 64), (32, 32, 96, 1), (32, 32, 96, 64),
+    (32, 8, 120, 1),
+], ids=["decode", "prefill", "phi3-decode", "phi3-prefill", "danube-decode"])
+def test_paged_attention(one_chip, h, hkv, d, tq):
+    """granite's 16 query / 8 KV heads of width 64, phi3-mini's 32 / 32 of
+    width 96 and h2o-danube3's 32 / 8 of width 120, over 16-token pages,
+    max_len 512 at batch 4: the page walk's DMAs slice whole lane rows out
+    of the pool."""
+    b, ps, w = 4, 16, 32
+    pages = kv_page_shape(b * w + 1, hkv, ps, d)
     text = _compile(
         one_chip,
         lambda q, k, v, bt, qs, ln: paged_flash_attention(
             q, k, v, bt, qs, ln),
-        _sds((b, h, tq, d)), _sds((pages, hkv, ps, d)),
-        _sds((pages, hkv, ps, d)), _sds((b, w), jnp.int32),
-        _sds((b,), jnp.int32), _sds((b,), jnp.int32))
+        _sds((b, h, tq, d)), _sds(pages), _sds(pages),
+        _sds((b, w), jnp.int32), _sds((b,), jnp.int32),
+        _sds((b,), jnp.int32))
     assert "tpu_custom_call" in text

@@ -135,7 +135,8 @@ def test_prefix_eviction_under_pressure():
 # ======================= paged attention vs dense oracle =====================
 
 def _dense_oracle(q, kd, vd, q_start, lengths, causal, window):
-    """Masked grouped-GQA softmax over DENSE per-request K/V (numpy f32)."""
+    """Masked grouped-GQA softmax over DENSE per-request K/V (numpy f32); a
+    query row with no key to attend to reads zeros."""
     b, h, tq, d = q.shape
     _, hkv, t, _ = kd.shape
     g = h // hkv
@@ -156,8 +157,19 @@ def _dense_oracle(q, kd, vd, q_start, lengths, causal, window):
             s = np.where(mask, s, -1e30)
             p = np.exp(s - s.max(axis=1, keepdims=True))
             p = p / np.maximum(p.sum(axis=1, keepdims=True), 1e-30)
+            p = np.where(mask.any(axis=1, keepdims=True), p, 0.0)
             out[bi, hi] = p @ vd[bi, kv_h].astype(np.float32)
     return out
+
+
+def _pack_pages(pages):
+    """(P, Hkv, ps, D) pages -> the pool layout of ``kv_page_shape``."""
+    from repro.kernels.flash_attention import kv_page_shape
+    p, hkv, ps, d = pages.shape
+    _, groups, _, lanes = kv_page_shape(p, hkv, ps, d)
+    rows = (pages.reshape(p, groups, hkv // groups, ps, d)
+            .transpose(0, 1, 3, 2, 4).reshape(p, groups, ps, -1))
+    return np.pad(rows, ((0, 0),) * 3 + ((0, lanes - rows.shape[-1]),))
 
 
 def _paged_setup(rng, b, hkv, g, tq, t_max, d, ps):
@@ -171,39 +183,76 @@ def _paged_setup(rng, b, hkv, g, tq, t_max, d, ps):
     kd = k_pages[bt].transpose(0, 2, 1, 3, 4).reshape(b, hkv, w * ps, d)
     vd = v_pages[bt].transpose(0, 2, 1, 3, 4).reshape(b, hkv, w * ps, d)
     q = rng.standard_normal((b, hkv * g, tq, d)).astype(np.float32)
-    return q, k_pages, v_pages, bt, kd, vd
+    return q, _pack_pages(k_pages), _pack_pages(v_pages), bt, kd, vd
 
 
-@pytest.mark.parametrize("tq,window", [(1, None), (6, None), (4, 7)])
-def test_paged_flash_matches_dense_oracle(rng, tq, window):
+def test_kv_page_shape_packs_heads_into_whole_lane_rows():
+    from repro.kernels.flash_attention import kv_page_shape
+    assert kv_page_shape(9, 8, 16, 64) == (9, 4, 16, 128)     # granite
+    assert kv_page_shape(9, 32, 16, 96) == (9, 8, 16, 384)    # phi3-mini
+    assert kv_page_shape(9, 8, 16, 128) == (9, 8, 16, 128)
+    assert kv_page_shape(9, 8, 16, 120) == (9, 8, 16, 128)    # h2o-danube3
+    assert kv_page_shape(9, 2, 8, 24) == (9, 1, 8, 48)        # no whole row
+
+
+@pytest.mark.parametrize("g,tq,window,d", [
+    pytest.param(2, 1, None, 64, id="1-None"),
+    pytest.param(2, 6, None, 64, id="6-None"),
+    pytest.param(2, 4, 7, 64, id="4-7"),
+    pytest.param(1, 1, None, 64, id="g1-decode"),
+    pytest.param(4, 1, None, 64, id="g4-decode"),
+    pytest.param(1, 6, None, 64, id="g1-tq6"),
+    pytest.param(4, 6, None, 64, id="g4-tq6"),
+    pytest.param(1, 64, None, 64, id="g1-chunk"),
+    pytest.param(2, 64, None, 64, id="g2-chunk"),
+    pytest.param(4, 64, None, 64, id="g4-chunk"),
+    pytest.param(2, 64, 7, 64, id="g2-chunk-window"),
+    pytest.param(2, 6, None, 128, id="g2-tq6-d128"),
+    pytest.param(2, 6, None, 120, id="g2-tq6-d120"),
+    pytest.param(2, 6, None, 24, id="g2-tq6-d24"),
+])
+def test_paged_flash_matches_dense_oracle(rng, g, tq, window, d):
+    """Rows end mid-page and mid-block (16-token pages, 8-page blocks); the
+    third row is an idle slot of length 0.  Every page no live range names
+    (the scratch page, dead table slots, the idle row's pages) holds NaN in
+    the kernel's pool: nothing outside the live ranges reaches the output."""
     from repro.kernels.flash_attention import paged_flash_attention
     from repro.models.attention import paged_attention_ref
 
-    b, hkv, g, d, ps, t_max = 2, 2, 2, 64, 8, 32
+    b, hkv, ps, t_max = 3, 4, 16, 200
     q, kp, vp, bt, kd, vd = _paged_setup(rng, b, hkv, g, tq, t_max, d, ps)
-    q_start = np.array([5, 17], np.int32)
-    lengths = q_start + tq                            # ragged: rows differ
+    q_start = np.array([5, 131, 0], np.int32)
+    lengths = (q_start + tq) * np.array([1, 1, 0], np.int32)
+    live_pages = {int(bt[bi, j]) for bi in range(b)
+                  for j in range(cdiv(int(lengths[bi]), ps))}
+    dead = np.array([p not in live_pages for p in range(kp.shape[0])])
+    kp_nan = np.where(dead[:, None, None, None], np.nan, kp)
+    vp_nan = np.where(dead[:, None, None, None], np.nan, vp)
 
     want = _dense_oracle(q, kd, vd, q_start, lengths, True, window)
     got_k = paged_flash_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
-        jnp.asarray(q_start), jnp.asarray(lengths), causal=True,
-        window=window, interpret=True)
+        jnp.asarray(q), jnp.asarray(kp_nan), jnp.asarray(vp_nan),
+        jnp.asarray(bt), jnp.asarray(q_start), jnp.asarray(lengths),
+        causal=True, window=window, interpret=True)
     got_r = paged_attention_ref(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
         jnp.asarray(q_start), jnp.asarray(lengths), causal=True,
         window=window)
     np.testing.assert_allclose(np.asarray(got_k), want, atol=2e-5, rtol=2e-5)
-    np.testing.assert_allclose(np.asarray(got_r), want, atol=2e-5, rtol=2e-5)
+    live = lengths > 0          # the XLA reference averages an idle row
+    np.testing.assert_allclose(np.asarray(got_r)[live], want[live],
+                               atol=2e-5, rtol=2e-5)
 
 
 def test_paged_kv_write_scatter(rng):
+    from repro.kernels.flash_attention import kv_page_shape
     from repro.models.attention import paged_kv_write
 
-    b, hkv, c, d, ps, w = 2, 2, 4, 8, 4, 3
+    b, hkv, c, d, ps, w = 2, 4, 4, 64, 4, 3
     n_pages = 1 + b * w
-    kp = jnp.zeros((n_pages, hkv, ps, d), jnp.float32)
-    vp = jnp.zeros((n_pages, hkv, ps, d), jnp.float32)
+    shape = kv_page_shape(n_pages, hkv, ps, d)
+    kp = jnp.zeros(shape, jnp.float32)
+    vp = jnp.zeros(shape, jnp.float32)
     bt = jnp.asarray(1 + np.arange(b * w).reshape(b, w), jnp.int32)
     q_start = jnp.asarray([2, 5], jnp.int32)
     n_valid = jnp.asarray([4, 2], jnp.int32)          # row 1: 2 dead slots
@@ -216,14 +265,15 @@ def test_paged_kv_write_scatter(rng):
         for i in range(int(n_valid[bi])):
             pos = int(q_start[bi]) + i
             pg, off = int(bt[bi, pos // ps]), pos % ps
-            np.testing.assert_array_equal(kp2[pg, :, off],
+            np.testing.assert_array_equal(kp2[pg, :, off].reshape(hkv, d),
                                           np.asarray(k_new)[bi, :, i])
-            np.testing.assert_array_equal(vp2[pg, :, off],
+            np.testing.assert_array_equal(vp2[pg, :, off].reshape(hkv, d),
                                           np.asarray(v_new)[bi, :, i])
     # Dead rows landed ONLY in the scratch page; real pages untouched
     # beyond the valid writes (count the nonzero rows).
     real = kp2[1:]
-    assert (np.abs(real) > 0).any(axis=-1).sum() == int(n_valid.sum()) * hkv
+    assert (np.abs(real) > 0).any(axis=-1).sum() == (
+        int(n_valid.sum()) * shape[1])
 
 
 # ============================== engine e2e ===================================

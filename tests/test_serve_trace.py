@@ -120,7 +120,9 @@ def test_engine_step_span_tree(engine_setup, tmp_path, registry):
         assert kid["serve.admit"]["step"] == rec.step
         assert kid["serve.schedule"] == {"preempted": rec.preemptions}
         assert kid["serve.dispatch"] == {
-            "new_shape": int(args["chunk"] not in widths_seen)}
+            "new_shape": int(args["chunk"] not in widths_seen),
+            "pages_walked": kid["serve.dispatch"]["pages_walked"]}
+        assert 1 <= kid["serve.dispatch"]["pages_walked"] <= 2 * 8
         widths_seen.add(args["chunk"])
         # Children run one after another.
         assert all(x[2] <= y[1] for x, y in zip(inside, inside[1:]))
@@ -130,6 +132,28 @@ def test_engine_step_span_tree(engine_setup, tmp_path, registry):
                if e[0] == "serve.commit") == 3
     shapes = registry.counter("serve_new_step_shapes_total")
     assert shapes.value(chunk=4) == 1.0 and shapes.value(chunk=1) == 1.0
+    assert registry.counter("serve_attention_pages_walked_total").value() \
+        == sum(e[3]["pages_walked"] for e in events
+               if e[0] == "serve.dispatch")
+    assert registry.counter("serve_attention_table_slots_total").value() \
+        == len(steps) * 2 * 8
+
+
+def test_attention_page_walk_counters(engine_setup, registry):
+    """Each step counts every row's live pages after its writes, and the
+    max_batch x table-width slots the kernel no longer walks."""
+    from repro.serve.engine import ServeEngine
+    cfg, model, params = engine_setup
+    eng = ServeEngine(model, params, max_len=64, max_batch=2, page_size=8,
+                      prefill_chunk=8)
+    eng.add_request(_prompt(cfg, 7, 0), max_new_tokens=3)
+    _drain(eng)
+    # Lengths 7, 8, 9 after the prefill and two decode steps: 1 + 1 + 2.
+    assert len(eng.step_telemetry) == 3
+    assert registry.counter(
+        "serve_attention_pages_walked_total").value() == 4.0
+    assert registry.counter(
+        "serve_attention_table_slots_total").value() == 3 * 2 * 8
 
 
 def test_untraced_step_records_nothing(engine_setup, tmp_path):
