@@ -43,7 +43,9 @@ from repro.core.gemm_spec import (
     get_epilogue, resolve_epilogue,
 )
 from repro.core.policy import PrecisionPolicy, get_policy, quantize_per_tensor
-from repro.kernels.mpgemm import mpgemm_pallas_spec
+from repro.kernels.mpgemm import (
+    RaggedTiles, mpgemm_pallas_spec, mpgemm_ragged_pallas,
+)
 from repro.packing.layout import PackedOperand, is_packed
 from repro.sparse.layout import TileSparseOperand, is_sparse
 
@@ -690,6 +692,69 @@ def mp_dot_grouped(
         rows = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
         y = jnp.where(rows < sizes, y, jnp.zeros_like(y))
     return y
+
+
+def mp_dot_ragged(
+    x: jax.Array,
+    b,
+    tiles: RaggedTiles,
+    *,
+    policy="bf16",
+    backend: Optional[str] = None,
+    out_dtype=None,
+    activation: Optional[str] = None,
+    gate: Optional[jax.Array] = None,
+    layer=None,
+) -> jax.Array:
+    """y[r] = tail(x[r] @ b[group of r's tile]) over a group-sorted buffer.
+
+    ``x``: (R, K) rows sorted by group, every group's rows starting on a
+    tile of ``R // T`` rows (``tiles``: the buffer's :class:`RaggedTiles`,
+    its live tiles first — ``kernels/mpgemm.py::ragged_layout``); ``b``:
+    (G, K, N) weights, dense, packed or tile-sparse, or with ``layer`` (an
+    int32 scalar) a dense (L, G, K, N) layer stack of which layer
+    ``layer`` is used; ``gate``: (R, N) for the gated epilogue.  Output
+    rows of the tiles past ``tiles.live`` are undefined.  Forward only:
+    the served MoE step's expert GEMMs.
+
+    Kernel backends under a float policy launch
+    :func:`~repro.kernels.mpgemm.mpgemm_ragged_pallas`, which computes only
+    the live tiles and reads each live group's weights once, from a layer
+    stack in place when its dtype is the compute dtype; packed and
+    tile-sparse weights are unpacked to dense for it.  Otherwise (the XLA
+    backend, dynamic int8) each tile is one group of a grouped GEMM
+    against its group's weights, gathered per tile.
+    """
+    policy = get_policy(policy)
+    backend = backend or cfg.get_gemm_backend()
+    out_dtype = jnp.dtype(out_dtype or policy.out_dtype)
+    kernel = backend in ("pallas", "interpret") and not policy.quantized
+    if layer is not None and not (
+            kernel and b.dtype == jnp.dtype(policy.compute_dtype)):
+        b = jax.lax.dynamic_index_in_dim(b, layer, keepdims=False)
+        layer = None
+    if is_packed(b):
+        from repro.packing.pack import unpack_operand
+        b = unpack_operand(b)
+    elif is_sparse(b):
+        from repro.sparse.sparsify import densify_operand
+        b = densify_operand(b)
+    else:
+        b = _dequant_static(b, policy)
+    if kernel:
+        return mpgemm_ragged_pallas(
+            x, b.astype(policy.compute_dtype), tiles, activation=activation,
+            gate=gate, layer=layer, out_dtype=out_dtype,
+            interpret=backend == "interpret")
+    n_tiles = tiles.group.shape[0]
+    r, k = x.shape
+    epilogue, extras = _build_epilogue(None, activation, gate, None, None)
+    extras = tuple(e.reshape(n_tiles, r // n_tiles, -1) for e in extras)
+    y = _apply_gemm(x.reshape(n_tiles, r // n_tiles, k),
+                    jnp.take(b, tiles.group, axis=0), None, extras,
+                    GemmSpec(grouped=True, out_dtype=str(out_dtype)),
+                    epilogue, policy, backend)
+    return y.reshape(r, -1)
 
 
 def _as_grouped_matmul(spec: str, n_ops: int) -> Optional[bool]:

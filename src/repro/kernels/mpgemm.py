@@ -25,11 +25,17 @@ path:
 packed B, and every registered epilogue are spec parameters of ONE body,
 not separate kernels.  :func:`mpgemm_pallas` / :func:`mpgemm_grouped_pallas`
 are thin argument-to-spec adapters kept as the public entry points.
+
+:func:`mpgemm_ragged_pallas` is the one launch beside the factory: a
+grouped GEMM over ONE row buffer sorted by group (the served MoE step's
+expert-sorted tokens), whose grid walks only the buffer's live row tiles
+and DMAs each group's weights once per run of its tiles.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence
+import functools
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -850,3 +856,246 @@ def mpgemm_grouped_pallas(
         scale=scale, extras=extras, spec=spec, epilogue=epilogue,
         out_dtype=out_dtype, plan=plan, interpret=interpret,
     )
+
+
+# --- ragged grouped launch: one group-sorted row buffer, live tiles only ----
+
+# Scoped-VMEM bytes the double-buffered weight block of a ragged launch may
+# take (of the 16 MiB limit; row tiles, gate and output blocks take the rest).
+RAGGED_WEIGHT_VMEM = 8 * 2**20
+
+
+class RaggedTiles(NamedTuple):
+    """Row-tile map of a group-sorted ragged buffer of ``T`` tiles.
+
+    ``group`` (T,) int32: the group whose weights each tile multiplies;
+    ``fetch`` (T,) int32: at the first tile of a group's run, the next live
+    group (whose weights the launch prefetches there), else -1;
+    ``live`` (1,) int32: how many tiles hold rows, a prefix of the T.
+    """
+    group: jax.Array
+    fetch: jax.Array
+    live: jax.Array
+
+
+def ragged_block_rows(rows: int, groups: int) -> int:
+    """Row-tile height for ``rows`` rows over ``groups`` groups: the next
+    power of two at or above the mean rows per group, within [8, 128]."""
+    mean = -(-rows // groups)
+    return min(128, max(8, 1 << max(0, mean - 1).bit_length()))
+
+
+def ragged_rows(rows: int, groups: int) -> int:
+    """Static size of a buffer holding ``rows`` rows with every group's
+    segment starting on a tile of :func:`ragged_block_rows` rows: each
+    group pads fewer than one tile."""
+    bm = ragged_block_rows(rows, groups)
+    return pl.cdiv(rows, bm) * bm + groups * bm
+
+
+def ragged_layout(counts: jax.Array, bm: int, n_tiles: int):
+    """-> (first row of each group's segment (G,), :class:`RaggedTiles`)
+    for groups of ``counts`` rows laid out in group order, each segment
+    padded to whole tiles of ``bm`` rows."""
+    counts = counts.astype(jnp.int32)
+    g = counts.shape[0]
+    per = (counts + bm - 1) // bm
+    ends = jnp.cumsum(per)
+    t = jnp.arange(n_tiles, dtype=jnp.int32)
+    group = jnp.minimum(jnp.searchsorted(ends, t, side="right"),
+                        g - 1).astype(jnp.int32)
+    # The next group with rows after each group (g: none).
+    ids = jnp.where(counts > 0, jnp.arange(g, dtype=jnp.int32), g)
+    after = jnp.append(jax.lax.cummin(ids, reverse=True)[1:], g)
+    first = jnp.append(True, group[1:] != group[:-1])
+    live = ends[-1]
+    fetch = jnp.where(first & (t < live) & (after[group] < g),
+                      after[group], -1).astype(jnp.int32)
+    return (ends - per) * bm, RaggedTiles(group, fetch, live.reshape(1))
+
+
+def _ragged_block_cols(k: int, n: int, itemsize: int) -> int:
+    """Widest column block of a (K, N) weight whose two VMEM buffers fit
+    :data:`RAGGED_WEIGHT_VMEM`: N itself, else a multiple of 128 lanes
+    dividing N."""
+    for bn in [n] + list(range(n - n % 128, 0, -128)):
+        if n % bn == 0 and 2 * k * bn * itemsize <= RAGGED_WEIGHT_VMEM:
+            return bn
+    raise ValueError(f"no column block of a ({k}, {n}) weight fits "
+                     f"{RAGGED_WEIGHT_VMEM} bytes of VMEM twice")
+
+
+def _ragged_kernel(x_hbm, w_hbm, group_ref, fetch_ref, live_ref, layer_ref,
+                   *refs, bm: int, bn: int, nb: int, gated: bool,
+                   epilogue: EpilogueSpec, compute_dtype):
+    """One column block of the ragged launch: a loop over the live tiles.
+
+    Per tile: its rows (and gate rows) arrive by DMA while the previous
+    tile computes; at the first tile of a group's run the group's weight
+    block (of layer ``layer_ref[0]`` of the stacked weights) is waited for
+    and the next live group's is started into the other buffer; the
+    tile's product and epilogue are stored by a DMA that is waited for two
+    tiles later.  Tiles past ``live`` are never touched.
+    """
+    if gated:
+        g_hbm, o_hbm, xbuf, wbuf, gbuf, obuf, sems = refs
+    else:
+        o_hbm, xbuf, wbuf, obuf, sems = refs
+    j = pl.program_id(0)
+    n_live = live_ref[0]
+    layer = layer_ref[0]
+
+    def cols(ref):
+        return ref if nb == 1 else ref.at[:, pl.ds(j * bn, bn)]
+
+    def rows(ref, t):
+        return ref.at[pl.ds(t * bm, bm)]
+
+    def x_copy(t, slot):
+        return pltpu.make_async_copy(rows(x_hbm, t), xbuf.at[slot],
+                                     sems.at[0, slot])
+
+    def g_copy(t, slot):
+        return pltpu.make_async_copy(cols(rows(g_hbm, t)), gbuf.at[slot],
+                                     sems.at[1, slot])
+
+    def w_copy(grp, slot):
+        return pltpu.make_async_copy(cols(w_hbm.at[layer, grp]),
+                                     wbuf.at[slot],
+                                     sems.at[2, slot])
+
+    def o_copy(t, slot):
+        return pltpu.make_async_copy(obuf.at[slot], cols(rows(o_hbm, t)),
+                                     sems.at[3, slot])
+
+    def fetch_rows(t, slot):
+        x_copy(t, slot).start()
+        if gated:
+            g_copy(t, slot).start()
+
+    @pl.when(n_live > 0)
+    def _prologue():
+        fetch_rows(0, 0)
+        w_copy(group_ref[0], 0).start()
+
+    def tile(t, wslot):
+        slot = t % 2
+        grp = group_ref[t]
+        new_run = jnp.logical_and(t > 0, grp != group_ref[jnp.maximum(t - 1, 0)])
+        wslot = jnp.where(new_run, 1 - wslot, wslot)
+
+        @pl.when(t + 1 < n_live)
+        def _next_rows():
+            fetch_rows(t + 1, 1 - slot)
+
+        @pl.when(jnp.logical_or(t == 0, new_run))
+        def _weights():
+            w_copy(grp, wslot).wait()
+            nxt = fetch_ref[t]
+
+            @pl.when(nxt >= 0)
+            def _next_weights():
+                w_copy(nxt, 1 - wslot).start()
+
+        x_copy(t, slot).wait()
+        acc = jax.lax.dot_general(
+            xbuf[slot].astype(compute_dtype), wbuf[wslot],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        extras = ()
+        if gated:
+            g_copy(t, slot).wait()
+            extras = (gbuf[slot],)
+        out = apply_epilogue(epilogue, acc, extras=extras)
+
+        @pl.when(t >= 2)
+        def _store_done():
+            o_copy(t - 2, slot).wait()
+
+        obuf[slot] = out.astype(obuf.dtype)
+        o_copy(t, slot).start()
+        return wslot
+
+    jax.lax.fori_loop(0, n_live, tile, jnp.int32(0))
+    for back in (2, 1):
+        @pl.when(n_live >= back)
+        def _drain():
+            t = n_live - back
+            o_copy(t, t % 2).wait()
+
+
+def mpgemm_ragged_pallas(
+    x: jax.Array,
+    w: jax.Array,
+    tiles: RaggedTiles,
+    *,
+    activation: Optional[str] = None,
+    gate: Optional[jax.Array] = None,
+    layer=None,
+    out_dtype=jnp.float32,
+    interpret: bool = False,
+) -> jax.Array:
+    """out[r] = tail(x[r] @ w[group of r's tile]) over the live row tiles.
+
+    ``x``: (R, K) rows sorted by group, cut into the ``T`` tiles of
+    ``tiles`` (``bm = R // T`` rows each, every group's rows starting on a
+    tile); ``w``: (G, K, N) in the compute dtype (``x`` is cast to it in
+    VMEM), or with ``layer`` (an int32 scalar) the (L, G, K, N) weights of
+    a layer stack, of which the launch reads layer ``layer`` in place, so
+    the caller's layer loop need not slice (copy) the whole stack;
+    ``gate``: (R, N), selecting the gated epilogue ``act(acc) · gate``
+    (registry, ``core/gemm_spec.py``).  Output rows of tiles past
+    ``tiles.live`` are left unwritten.
+
+    The grid runs over column blocks of N (one where two (K, N) weight
+    blocks fit :data:`RAGGED_WEIGHT_VMEM`); inside, a loop walks the live
+    tiles with every operand in HBM (``pl.ANY``) and moved by manual DMA:
+    row tiles double-buffered, a group's weight block fetched once per run
+    of its tiles and the next live group's fetched under the current
+    run's products, stores in flight behind the next tile.  The row
+    buffer is the first operand and the tile map and layer follow the
+    data in SMEM, so no operand the launch reads before its rows is an
+    int32 scalar-prefetch array.
+    """
+    if layer is None:
+        w, layer = w[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    r, k = x.shape
+    _, g, kw, n = w.shape
+    n_tiles = tiles.group.shape[0]
+    if kw != k or r % n_tiles:
+        raise ValueError(f"ragged operands mismatch: x {x.shape}, w "
+                         f"{w.shape}, {n_tiles} tiles")
+    bm = r // n_tiles
+    epilogue, extras = resolve_epilogue({"gate": gate},
+                                        activation=activation)
+    gated = gate is not None
+    bn = _ragged_block_cols(k, n, jnp.dtype(w.dtype).itemsize)
+    out_dtype = jnp.dtype(out_dtype)
+    anyspace = pl.BlockSpec(memory_space=pl.ANY)
+    smem = pl.BlockSpec(memory_space=None if interpret else pltpu.SMEM)
+    scratch = [pltpu.VMEM((2, bm, k), x.dtype),
+               pltpu.VMEM((2, k, bn), w.dtype)]
+    if gated:
+        scratch.append(pltpu.VMEM((2, bm, bn), gate.dtype))
+    scratch += [pltpu.VMEM((2, bm, bn), out_dtype),
+                pltpu.SemaphoreType.DMA((4, 2))]
+    kernel = functools.partial(
+        _ragged_kernel, bm=bm, bn=bn, nb=n // bn, gated=gated,
+        epilogue=epilogue, compute_dtype=w.dtype)
+    obs.counter_inc("gemm_launches_total",
+                    help="GEMM launches by spec combination",
+                    layout="dense", codec="none", epilogue=epilogue.kind,
+                    sparse="false", grouped="ragged")
+    return pl.pallas_call(
+        kernel,
+        grid=(n // bn,),
+        in_specs=[anyspace, anyspace, smem, smem, smem, smem]
+        + [anyspace] * len(extras),
+        out_specs=anyspace,
+        out_shape=jax.ShapeDtypeStruct((r, n), out_dtype),
+        scratch_shapes=scratch,
+        name="mpgemm_ragged",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(x, w, tiles.group, tiles.fetch, tiles.live, layer, *extras)

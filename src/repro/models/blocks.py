@@ -22,9 +22,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import config as gemm_cfg
-from repro.core.gemm import mp_dot, mp_dot_grouped
+from repro.core.gemm import mp_dot, mp_dot_grouped, mp_dot_ragged
 from repro.distributed import act
 from repro.kernels.flash_attention import kv_page_shape
+from repro.kernels.mpgemm import (
+    ragged_block_rows, ragged_layout, ragged_rows,
+)
 from repro.models import attention as attn
 from repro.models.layers import (
     apply_rope, dense_init, gelu_mlp, init_gelu_mlp, init_swiglu, layernorm,
@@ -333,6 +336,10 @@ def _expert_dot(ebuf, w, policy, **fusion):
                           **fusion)
 
 
+# The expert weights of a MoE block, (E, ., .) each.
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
 def init_moe(key, cfg):
     d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
@@ -437,6 +444,67 @@ def moe_mlp(params, x, cfg, policy, capacity_factor: float = 1.25):
     return out.astype(x.dtype), 0.01 * aux + 0.001 * zloss
 
 
+def moe_mlp_ragged(params, x, live, cfg, policy):
+    """Dropless top-k MoE over the live tokens of a served step.
+
+    With ``params["layer"]`` (an int32 scalar) the expert weights are the
+    (L, E, ., .) stacks of a layer scan and layer ``layer`` of them is
+    read in place (``LM.paged_step`` hoists them out of its scan).
+
+    ``x`` (B, C, d), ``live`` (B, C) bool.  Every token is routed (the
+    router GEMM and top-k as in :func:`moe_mlp`), but only the live
+    tokens' (token, expert) pairs take rows: a counting sort by expert
+    (dead pairs keyed past the last expert) places them in ONE row buffer
+    whose expert segments each start on a row tile of ``bm`` rows
+    (``kernels/mpgemm.py::ragged_block_rows``), with the tile map of the
+    buffer's live tiles.  The three expert GEMMs (:func:`mp_dot_ragged`)
+    compute only those tiles, reading each routed expert's weights once;
+    the combine gathers each live pair's output row and weights it by its
+    top-k weight.  No capacity exists, so nothing is dropped for any
+    ``top_k / n_experts``; dead tokens get zeros.  Returns (B, C, d)."""
+    b, c, d = x.shape
+    e, k = cfg.n_experts, cfg.experts_per_token
+    t = b * c
+    xf = x.reshape(t, d)
+    logits = mp_dot(xf, params["router"], policy="fp32").astype(jnp.float32)
+    topw, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)   # (t,k)
+    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    live = live.reshape(t)
+    # Counting sort by expert: a token picks each expert at most once, so
+    # a live pair's rank in its expert is how many earlier live tokens
+    # picked that expert.
+    picked = (jnp.any(topi[:, :, None] == jnp.arange(e), axis=1)
+              & live[:, None]).astype(jnp.int32)                      # (t,e)
+    rank = jnp.cumsum(picked, axis=0) - picked
+    bm = ragged_block_rows(t * k, e)
+    r = ragged_rows(t * k, e)
+    start, tiles = ragged_layout(picked.sum(0), bm, r // bm)
+    row = jnp.where(live[:, None],
+                    start[topi] + jnp.take_along_axis(rank, topi, axis=1),
+                    r).reshape(-1)                                    # (t*k,)
+    # row -> source token (int32 scatter); padding rows read zeros.
+    src = jnp.full((r,), t, jnp.int32).at[row].set(
+        jnp.repeat(jnp.arange(t, dtype=jnp.int32), k), mode="drop")
+    buf = jnp.take(xf, src, axis=0, mode="fill", fill_value=0)        # (r,d)
+
+    def expert_dot(a, w, **fusion):
+        return mp_dot_ragged(a, w, tiles, policy=policy,
+                             layer=params.get("layer"),
+                             out_dtype=jnp.float32, **fusion)
+
+    h_up = expert_dot(buf, params["w_up"])
+    if gemm_cfg.fused_epilogues():
+        h = expert_dot(buf, params["w_gate"], activation="silu", gate=h_up)
+    else:
+        h = jax.nn.silu(expert_dot(buf, params["w_gate"])) * h_up
+    y = expert_dot(h, params["w_down"])                               # (r,d)
+    contrib = jnp.take(y, row, axis=0, mode="fill",
+                       fill_value=0).reshape(t, k, d)
+    w = jnp.where(live[:, None], topw, 0.0)
+    out = jnp.einsum("tkd,tk->td", contrib, w)
+    return out.reshape(b, c, d).astype(x.dtype)
+
+
 def moe_fwd(params, x, ctx, *, window=None):
     cfg = ctx["cfg"]
     o, kv = _self_attention(params["attn"], norm(params["ln1"], x, cfg), ctx, window)
@@ -466,8 +534,10 @@ def moe_paged_step(params, x, cache, ctx, *, window=None):
     o, cache = attn_paged_step(params["attn"], norm(params["ln1"], x, cfg),
                                cache, ctx, window=window)
     x = x + o
-    y, _ = moe_mlp(params, norm(params["ln2"], x, cfg), cfg, ctx["policy"],
-                   capacity_factor=ctx.get("moe_capacity", 1.25))
+    pg = ctx["paged"]
+    live = jnp.arange(x.shape[1])[None, :] < pg["n_valid"][:, None]
+    y = moe_mlp_ragged(params, norm(params["ln2"], x, cfg), live, cfg,
+                       ctx["policy"])
     return x + y, cache
 
 

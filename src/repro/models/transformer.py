@@ -449,7 +449,6 @@ class LM:
                                          cfg.rope_theta)
         ctx = self._ctx(c, rope_rows=rope_rows)
         ctx["positions"] = positions
-        ctx["moe_capacity"] = 4.0   # serve-time: effectively dropless
         ctx["paged"] = {
             "block_tables": block_tables.astype(jnp.int32),
             "q_start": q_start.astype(jnp.int32),
@@ -457,17 +456,31 @@ class LM:
             "lengths": (q_start + n_valid).astype(jnp.int32),
         }
 
+        # The MoE expert weight stacks stay out of the scan's slices: the
+        # ragged expert launch reads only the routed experts of layer i
+        # in place, where a per-layer slice would copy all E experts.
+        stacks = [{k: unit[k] for k in B.EXPERT_WEIGHTS}
+                  if kind == "moe" and all(isinstance(unit[k], jax.Array)
+                                           for k in B.EXPERT_WEIGHTS)
+                  else {}
+                  for kind, unit in zip(self.unit, params["stack"])]
+        sliced = [{k: v for k, v in unit.items() if k not in hoisted}
+                  for unit, hoisted in zip(params["stack"], stacks)]
+
         def body(x, xs):
-            unit_params, unit_caches = xs
+            unit_params, unit_caches, i = xs
             new = []
             for p, kind in enumerate(self.unit):
-                x, cc = block_paged_step(kind, unit_params[p], x,
-                                         unit_caches[p], ctx)
+                bp = unit_params[p]
+                if stacks[p]:
+                    bp = {**bp, **stacks[p], "layer": i}
+                x, cc = block_paged_step(kind, bp, x, unit_caches[p], ctx)
                 new.append(cc)
             return x, new
 
         x, new_stack = jax.lax.scan(
-            body, x, (params["stack"], caches["stack"]))
+            body, x, (sliced, caches["stack"],
+                      jnp.arange(self.n_units, dtype=jnp.int32)))
         new_tail = []
         for i, kind in enumerate(self.rem):
             x, cc = block_paged_step(kind, params["tail"][i], x,

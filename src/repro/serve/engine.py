@@ -28,7 +28,10 @@ reservation, host batch arrays), ``serve.dispatch`` (uploads and the
 jitted call, until it returns; ``pages_walked``: the live KV pages the
 attention kernel walks per layer, beside the
 ``serve_attention_pages_walked_total`` / ``_table_slots_total``
-counters), ``serve.sync`` (waiting for the chip and
+counters; for MoE models ``serve_expert_rows_live_total``, the live
+(token, expert) rows of each MoE layer, over
+``serve_expert_rows_buffer_total``, the rows of its expert-sorted
+buffer, both by phase), ``serve.sync`` (waiting for the chip and
 copying the logits back), ``serve.commit`` (sampling, per-slot updates)
 and ``serve.telemetry``.  Each :class:`Request` carries host-clock stamps
 (``time.perf_counter()``) of its queueing, admission and first token.
@@ -46,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.kernels.mpgemm import ragged_rows
 from repro.serve.kv_cache import PagedKVCache, cdiv
 
 
@@ -198,6 +202,9 @@ class ServeEngine:
                               else max(page_size, 8))
         self.caches = model.init_paged_caches(self.max_pages, page_size)
         self._step_fn = jax.jit(model.paged_step)
+        # top_k of the MoE layers (0: none) for the expert-row counters.
+        self._moe_k = (model.cfg.experts_per_token
+                       if "moe" in model.cfg.pattern else 0)
         self._chunks_dispatched: Set[int] = set()
         self._slots: List[Optional[_Slot]] = [None] * self.max_batch
         self._queue: deque = deque()
@@ -325,7 +332,7 @@ class ServeEngine:
                                             for s in live)
                  else "mixed" if chunk else "decode")
         with obs.span("serve.schedule"):
-            tokens, q_start, n_valid, bt, walked = self._schedule(c)
+            tokens, q_start, n_valid, bt, walked = self._schedule(c, phase)
             obs.annotate(preempted=self._preempted_now)
         live = [s for s in self._slots if s is not None]
         obs.annotate(phase=phase, live=len(live), chunk=c)
@@ -363,7 +370,7 @@ class ServeEngine:
         self._step_counter += 1
         return finished
 
-    def _schedule(self, c: int):
+    def _schedule(self, c: int, phase: str):
         """Reserve pages for this step's writes (may preempt) and build the
         host batch: (tokens (B, c), q_start (B,), n_valid (B,), block
         tables (B, W), the live pages the attention kernel walks in each
@@ -404,6 +411,18 @@ class ServeEngine:
                         amount=b * self.bt_width,
                         help="block-table slots of the steps, per layer "
                              "(max_batch x table width)")
+        if self._moe_k:
+            obs.counter_inc("serve_expert_rows_live_total",
+                            amount=int(n_valid.sum()) * self._moe_k,
+                            phase=phase,
+                            help="live (token, expert) rows of the MoE "
+                                 "expert GEMMs, per MoE layer")
+            obs.counter_inc("serve_expert_rows_buffer_total",
+                            amount=ragged_rows(b * c * self._moe_k,
+                                               self.model.cfg.n_experts),
+                            phase=phase,
+                            help="rows of the expert-sorted buffer, per "
+                                 "MoE layer")
         return tokens, q_start, n_valid, bt, walked
 
     def _commit(self, logits: np.ndarray, n_valid: np.ndarray):

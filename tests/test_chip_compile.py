@@ -8,6 +8,8 @@ topology is described inside a fixture, never at import, and every test
 skips when the TPU compiler cannot describe it.
 """
 import os
+import re
+import sys
 
 import pytest
 
@@ -17,8 +19,16 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.blocking import plan_gemm, plan_with_blocks
 from repro.kernels.flash_attention import kv_page_shape, paged_flash_attention
-from repro.kernels.mpgemm import mpgemm_grouped_pallas, mpgemm_pallas
+from repro.kernels.mpgemm import (
+    RaggedTiles, mpgemm_grouped_pallas, mpgemm_pallas, mpgemm_ragged_pallas,
+    ragged_block_rows, ragged_rows,
+)
 from repro.packing.pack import pack_operand
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+from benchmarks.chip.metrics import (  # noqa: E402
+    mpgemm_roofline, paged_attention_roofline,
+)
 
 BF16 = jnp.bfloat16
 
@@ -123,3 +133,83 @@ def test_paged_attention(one_chip, h, hkv, d, tq):
         _sds((b, w), jnp.int32), _sds((b,), jnp.int32),
         _sds((b,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("tokens", [8, 512], ids=["decode", "mixed"])
+def test_ragged_expert_launch(one_chip, tokens):
+    """granite's three expert GEMMs over the expert-sorted buffer of a
+    step of ``tokens`` tokens (top-8 of 32): bf16 rows into the up and
+    the gated gate projection, f32 rows into the down projection."""
+    e, d, f, k = 32, 1024, 512, 8
+    bm = ragged_block_rows(tokens * k, e)
+    r = ragged_rows(tokens * k, e)
+    i32 = jnp.int32
+    tiles = RaggedTiles(_sds((r // bm,), i32), _sds((r // bm,), i32),
+                        _sds((1,), i32))
+    for fn, ops in [
+        (lambda x, w, t: mpgemm_ragged_pallas(x, w, t),
+         (_sds((r, d)), _sds((e, d, f)))),
+        (lambda x, w, t, g: mpgemm_ragged_pallas(
+            x, w, t, activation="silu", gate=g),
+         (_sds((r, d)), _sds((e, d, f)), _sds((r, f), jnp.float32))),
+        (lambda x, w, t: mpgemm_ragged_pallas(x, w, t),
+         (_sds((r, f), jnp.float32), _sds((e, f, d)))),
+    ]:
+        text = _compile(one_chip, lambda x, w, t, *g: fn(x, w, t, *g),
+                        ops[0], ops[1], tiles, *ops[2:])
+        assert "tpu_custom_call" in text
+
+
+def _hlo_with_operand_shapes(compiled) -> str:
+    """Compiled HLO printed as the profiler names its ops: every operand
+    with its shape, so a custom call's first operand type is visible."""
+    from jax._src.lib import xla_client
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    return "\n".join(m.to_string(opts) for m in
+                     compiled.runtime_executable().hlo_modules())
+
+
+@pytest.mark.parametrize("chunk", [1, 64], ids=["decode", "mixed"])
+def test_paged_step_kernel_classes(one_chip, chunk):
+    """granite's whole served step: the benchmark's kernel classes, told
+    apart by a custom call's first operand, count every GEMM launch
+    (dense and ragged expert) as ``mpgemm`` and only the attention kernel
+    as ``paged_attention``.  The ragged launches read the experts of the
+    whole (24, 32, ., .) weight stacks, so no per-layer copy of them is
+    made outside the GEMM kernels."""
+    from repro.configs import base as cb
+    from repro.core import config as gemm_cfg
+    from repro.models.transformer import build_model
+    model = build_model(cb.get("granite-moe-1b-a400m"), policy="bf16_serve",
+                        remat=False)
+    b, w = 8, 8
+    args = (jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+            _sds((b, chunk), jnp.int32),
+            jax.eval_shape(lambda: model.init_paged_caches(b * w + 1, 16)),
+            _sds((b, w), jnp.int32), _sds((b,), jnp.int32),
+            _sds((b,), jnp.int32))
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    with gemm_cfg.gemm_backend("pallas"):
+        compiled = jax.jit(model.paged_step).lower(*args).compile()
+    gemm = re.compile(mpgemm_roofline.KERNELS["mpgemm"])
+    attention = re.compile(paged_attention_roofline.KERNELS["paged_attention"])
+    calls = [line.strip() for line in
+             _hlo_with_operand_shapes(compiled).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = [re.match(r"(?:ROOT )?%?(\S+) = ", c).group(1) for c in calls]
+    ragged = [c for n, c in zip(names, calls) if n.startswith("mpgemm_ragged")]
+    assert len(ragged) == 3
+    for call in ragged:
+        assert re.search(r"custom-call\(\S+ %[^,]+, bf16\[24,32,\d+,\d+\]",
+                         call)
+    assert not re.search(r"= bf16\[32,(512,1024|1024,512)\]",
+                         _hlo_with_operand_shapes(compiled))
+    assert sum(n.startswith("paged_flash_attention") for n in names) == 1
+    for name, call in zip(names, calls):
+        is_attention = name.startswith("paged_flash_attention")
+        assert is_attention or name.startswith("mpgemm"), name
+        assert bool(attention.search(call)) == is_attention, name
+        assert bool(gemm.search(call)) != is_attention, name

@@ -1,6 +1,7 @@
 """mp_dot_grouped / mpgemm_grouped_pallas: einsum equivalence across the
 precision policies, ragged groups via masking, fused-transpose VJP, and the
-grouped plan/cache plumbing."""
+grouped plan/cache plumbing; mpgemm_ragged_pallas / mp_dot_ragged: the
+group-sorted ragged launch against a per-segment einsum."""
 import numpy as np
 import pytest
 
@@ -8,8 +9,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.blocking import plan_gemm, plan_grouped_gemm
-from repro.core.gemm import mp_dot_grouped, mp_einsum
-from repro.kernels.mpgemm import mpgemm_grouped_pallas
+from repro.core.gemm import mp_dot_grouped, mp_dot_ragged, mp_einsum
+from repro.kernels.mpgemm import (
+    mpgemm_grouped_pallas, mpgemm_ragged_pallas, ragged_block_rows,
+    ragged_layout, ragged_rows,
+)
 from repro.tuning import PlanCache, make_key, set_plan_cache, tune_grouped_gemm
 
 G, M, K, N = 4, 24, 40, 24
@@ -226,3 +230,150 @@ def test_mp_einsum_routes_grouped_specs(ops):
                     jnp.ones((2, 2, 4, 8)), jnp.ones((2, 2, 4, 8)),
                     policy="fp32")
     assert att.shape == (2, 2, 4, 4)
+
+
+def _ragged_case(rng, counts, bm, k, n, x_dtype=jnp.bfloat16):
+    """A group-sorted buffer for ``counts`` rows per group, laid out by
+    ragged_layout: -> (x, w, tiles, group of each row (-1: padding))."""
+    counts = np.asarray(counts, np.int32)
+    g = len(counts)
+    r = -(-int(counts.sum()) // bm) * bm + g * bm
+    start, tiles = ragged_layout(jnp.asarray(counts), bm, r // bm)
+    x = np.zeros((r, k), np.float32)
+    grp = np.full((r,), -1)
+    for gi, (s0, c) in enumerate(zip(np.asarray(start), counts)):
+        x[s0:s0 + c] = rng.standard_normal((c, k))
+        grp[s0:s0 + c] = gi
+    w = rng.standard_normal((g, k, n)).astype(np.float32)
+    return (jnp.asarray(x, x_dtype), jnp.asarray(w, jnp.bfloat16), tiles,
+            grp)
+
+
+def _segment_ref(x, w, grp):
+    """float32 per-segment einsum over the rows that hold a group."""
+    xf = np.asarray(x, np.float32)
+    wf = np.asarray(w.astype(jnp.float32))
+    ref = np.zeros((xf.shape[0], wf.shape[2]), np.float32)
+    for gi in range(wf.shape[0]):
+        rows = grp == gi
+        ref[rows] = np.einsum("mk,kn->mn", xf[rows], wf[gi])
+    return ref
+
+
+@pytest.mark.parametrize("counts,bm,gated,x_dtype,col_blocks,layers", [
+    ([5, 0, 12, 0, 3], 8, False, jnp.bfloat16, 1, 0),
+    ([0, 0, 40, 0], 8, True, jnp.bfloat16, 1, 0),
+    ([300, 0, 129, 1], 128, False, jnp.bfloat16, 1, 0),
+    ([17, 140, 0, 0, 9], 128, True, jnp.float32, 1, 0),
+    ([0, 0, 0], 8, False, jnp.bfloat16, 1, 0),
+    ([20, 0, 3], 8, True, jnp.bfloat16, 2, 0),
+    ([0, 11, 0, 30], 8, True, jnp.bfloat16, 1, 3),
+], ids=["empty-groups-bm8", "one-group-all-rows-gated-bm8",
+        "multi-tile-segments-bm128", "gated-f32-rows-bm128", "no-rows",
+        "two-column-blocks-gated-bm8", "layer-of-a-stack-gated-bm8"])
+def test_ragged_launch_matches_segment_einsum(rng, monkeypatch, counts, bm,
+                                              gated, x_dtype, col_blocks,
+                                              layers):
+    """Rows of live tiles match a float32 einsum per segment, with f32
+    out; the tile map holds fewer live tiles than the buffer's tiles.
+    ``col_blocks`` 2 shrinks the weight VMEM budget so that the launch
+    walks the tiles once per half of N; ``layers`` 3 hands the launch a
+    stack of three layers' weights, of which it must read the second."""
+    k, n = 64, 256
+    if col_blocks > 1:
+        monkeypatch.setattr("repro.kernels.mpgemm.RAGGED_WEIGHT_VMEM",
+                            2 * k * (n // col_blocks) * 2)
+    x, w, tiles, grp = _ragged_case(rng, counts, bm, k, n, x_dtype)
+    n_live = int(tiles.live[0])
+    assert n_live == sum(-(-c // bm) for c in counts)
+    assert n_live < tiles.group.shape[0]
+    ref = _segment_ref(x.astype(jnp.bfloat16), w, grp)
+    gate = None
+    if gated:
+        gate = jnp.asarray(rng.standard_normal(ref.shape), jnp.float32)
+        ref = np.asarray(jax.nn.silu(ref)) * np.asarray(gate)
+    layer = None
+    if layers:
+        layer = jnp.int32(1)
+        w = jnp.stack([-w if i != 1 else w for i in range(layers)])
+    y = mpgemm_ragged_pallas(x, w, tiles, activation="silu" if gated
+                             else None, gate=gate, layer=layer,
+                             interpret=True)
+    assert y.dtype == jnp.float32 and y.shape == (x.shape[0], n)
+    rows = grp >= 0
+    np.testing.assert_allclose(np.asarray(y)[rows], ref[rows],
+                               rtol=1e-5, atol=1e-4)
+    # Padding rows of live tiles multiply zero rows.
+    pad = (grp < 0) & (np.arange(x.shape[0]) < n_live * bm)
+    np.testing.assert_allclose(np.asarray(y)[pad], 0.0, atol=1e-6)
+
+
+def test_ragged_tile_map():
+    """Segments start on tile boundaries; each group's first tile names
+    the next group with rows for the weight prefetch."""
+    start, tiles = ragged_layout(jnp.asarray([3, 0, 17, 1, 0]), 8, 8)
+    np.testing.assert_array_equal(start, [0, 8, 8, 32, 40])
+    np.testing.assert_array_equal(tiles.group[:5], [0, 2, 2, 2, 3])
+    np.testing.assert_array_equal(tiles.fetch, [2, 3, -1, -1, -1, -1, -1,
+                                                -1])
+    assert int(tiles.live[0]) == 5
+    assert [ragged_block_rows(p, 32) for p in (8, 64, 512, 4096, 9000)] \
+        == [8, 8, 16, 128, 128]
+    assert ragged_rows(4096, 32) == 8192 and ragged_rows(64, 32) == 320
+
+
+@pytest.mark.parametrize("policy", ["fp32", "bf16", "int8"])
+def test_mp_dot_ragged_backends_agree(rng, policy):
+    """The kernel (interpret) and the XLA per-tile grouped GEMM agree."""
+    x, w, tiles, grp = _ragged_case(rng, [9, 0, 20], 8, 32, 128)
+    w = w.astype(jnp.float32)
+    a = mp_dot_ragged(x, w, tiles, policy=policy, backend="xla",
+                      out_dtype=jnp.float32)
+    b = mp_dot_ragged(x, w, tiles, policy=policy, backend="interpret",
+                      out_dtype=jnp.float32)
+    rows = grp >= 0
+    np.testing.assert_allclose(np.asarray(a)[rows], np.asarray(b)[rows],
+                               rtol=1e-5, atol=1e-4)
+    ref = _segment_ref(x, w, grp)[rows]
+    err = np.abs(np.asarray(a)[rows] - ref).max()
+    assert err < (1e-4 if policy != "int8" else 0.05 * np.abs(ref).max())
+
+
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
+@pytest.mark.parametrize("stack_dtype", [jnp.bfloat16, jnp.float32])
+def test_mp_dot_ragged_reads_one_layer_of_a_stack(rng, backend, stack_dtype):
+    """``layer`` picks one layer of an (L, G, K, N) stack, read in place
+    by the kernel when the stack is in the compute dtype and sliced
+    first otherwise; the rows equal those of that layer alone."""
+    x, w, tiles, grp = _ragged_case(rng, [9, 0, 20], 8, 32, 128)
+    stack = jnp.stack([w * (i + 1) for i in range(3)]).astype(stack_dtype)
+    rows = grp >= 0
+    got = mp_dot_ragged(x, stack, tiles, policy="bf16", backend=backend,
+                        layer=jnp.int32(2), out_dtype=jnp.float32)
+    want = mp_dot_ragged(x, stack[2], tiles, policy="bf16",
+                         backend=backend, out_dtype=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(got)[rows],
+                                  np.asarray(want)[rows])
+
+
+@pytest.mark.parametrize("operand", ["packed", "sparse"])
+def test_mp_dot_ragged_reads_packed_and_sparse_weights(rng, operand):
+    """Pre-laid-out expert weights (``pack_params``/``sparsify_params``
+    output) give the rows of the dense weights they stand for."""
+    from repro.packing.pack import pack_operand
+    from repro.sparse.sparsify import densify_operand, sparsify_magnitude
+    x, w, tiles, grp = _ragged_case(rng, [9, 0, 20], 8, 32, 128)
+    if operand == "packed":
+        op = pack_operand(w, (16, 128), backend="interpret")
+        dense = w
+    else:
+        op = sparsify_magnitude(w, (16, 128), density=0.5)
+        dense = densify_operand(op)
+    rows = grp >= 0
+    for backend in ("xla", "interpret"):
+        got = mp_dot_ragged(x, op, tiles, policy="bf16", backend=backend,
+                            out_dtype=jnp.float32)
+        want = mp_dot_ragged(x, dense, tiles, policy="bf16",
+                             backend=backend, out_dtype=jnp.float32)
+        np.testing.assert_allclose(np.asarray(got)[rows],
+                                   np.asarray(want)[rows], atol=1e-6)

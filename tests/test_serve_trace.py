@@ -156,6 +156,35 @@ def test_attention_page_walk_counters(engine_setup, registry):
         "serve_attention_table_slots_total").value() == 3 * 2 * 8
 
 
+def test_expert_row_counters(registry):
+    """A MoE model's steps count their live (token, expert) rows and the
+    rows of the expert-sorted buffer, per MoE layer, by phase."""
+    from repro.configs import base as cb
+    from repro.kernels.mpgemm import ragged_rows
+    from repro.models.transformer import build_model
+    from repro.serve.engine import ServeEngine
+    cfg = cb.get("granite-moe-1b-a400m", smoke=True)
+    model = build_model(cfg, policy="bf16", remat=False)
+    eng = ServeEngine(model, model.init(jax.random.PRNGKey(0)), max_len=64,
+                      max_batch=2, page_size=8, prefill_chunk=8)
+    eng.add_request(_prompt(cfg, 7, 0), max_new_tokens=3)
+    _drain(eng)
+    # A prefill step of width 7 (7 live tokens), then two decode steps.
+    assert [s.phase for s in eng.step_telemetry] == [
+        "prefill", "decode", "decode"]
+    k, e = cfg.experts_per_token, cfg.n_experts
+
+    def buffer(tokens):
+        return ragged_rows(tokens * k, e)
+
+    live = registry.counter("serve_expert_rows_live_total")
+    rows = registry.counter("serve_expert_rows_buffer_total")
+    assert live.value(phase="prefill") == 7 * k
+    assert live.value(phase="decode") == 2 * k
+    assert rows.value(phase="prefill") == buffer(2 * 7) == 120
+    assert rows.value(phase="decode") == 2 * buffer(2 * 1) == 2 * 72
+
+
 def test_untraced_step_records_nothing(engine_setup, tmp_path):
     from repro.serve.engine import ServeEngine
     cfg, model, params = engine_setup
